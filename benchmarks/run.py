@@ -50,7 +50,8 @@ _SUITES: list[tuple[str, str, str]] = [
      "vs per-camera stages (beyond-paper)", "pipeline_consolidation"),
     ("forecast_mpc", "seasonal forecast + MPC autoscaling vs reactive "
      "(beyond-paper)", "forecast_mpc"),
-    ("kernels", "pallas kernels (interpret-mode validation)",
+    ("kernels", "pallas kernels vs oracle (compiled on TPU, interpreted on "
+     "CPU)",
      "kernel_sweep"),
 ]
 

@@ -17,14 +17,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             scale: float, causal: bool, window: int, bq: int, bk: int,
-            nk: int, q_off: int):
+            nk: int, q_off: int, kv_len: int):
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -43,6 +41,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     q_idx = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + q_off
     k_idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     mask = jnp.ones((bq, bk), jnp.bool_)
+    if kv_len < nk * bk:                # right-padded keys
+        mask &= k_idx < kv_len
     if causal:
         mask &= k_idx <= q_idx
     if window > 0:
@@ -69,27 +69,34 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
-    """q: (B,S,H,hd); k,v: (B,T,K,hd). Returns (B,S,H,hd)."""
+                    interpret: bool = False) -> jnp.ndarray:
+    """q: (B,S,H,hd); k,v: (B,T,K,hd). Returns (B,S,H,hd).
+
+    Any S and T: a length above its block size that is not a multiple of it
+    is right-padded to whole blocks. Padded keys are masked out and padded
+    query rows are dropped, so the result is exact."""
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
     bq = min(bq, S)
     bk = min(bk, T)
-    assert S % bq == 0 and T % bk == 0, (S, bq, T, bk)
-    nq, nk = S // bq, T // bk
+    nq, nk = -(-S // bq), -(-T // bk)
     q_off = T - S                       # queries are the last S of T positions
 
-    qf = jnp.moveaxis(q, 2, 1).reshape(B * H, S, hd)
-    kf = jnp.moveaxis(k, 2, 1).reshape(B * K, T, hd)
-    vf = jnp.moveaxis(v, 2, 1).reshape(B * K, T, hd)
+    def rows(x, n):                     # (B,L,heads,hd) -> (B*heads, n, hd)
+        x = jnp.moveaxis(x, 2, 1)
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, n - x.shape[2]), (0, 0)))
+        return x.reshape(-1, n, hd)
+
+    qf, kf, vf = rows(q, nq * bq), rows(k, nk * bk), rows(v, nk * bk)
 
     def kv_row(bh, i, j):
         return (bh // H) * K + (bh % H) // G
 
     out = pl.pallas_call(
         functools.partial(_kernel, scale=1.0 / math.sqrt(hd), causal=causal,
-                          window=window, bq=bq, bk=bk, nk=nk, q_off=q_off),
+                          window=window, bq=bq, bk=bk, nk=nk, q_off=q_off,
+                          kv_len=T),
         grid=(B * H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, hd), lambda bh, i, j: (bh, i, 0)),
@@ -97,15 +104,15 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pl.BlockSpec((1, bk, hd), lambda bh, i, j: (kv_row(bh, i, j), j, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, hd), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, nq * bq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="flash_attention",
     )(qf, kf, vf)
-    return jnp.moveaxis(out.reshape(B, H, S, hd), 1, 2)
+    return jnp.moveaxis(out[:, :S].reshape(B, H, S, hd), 1, 2)

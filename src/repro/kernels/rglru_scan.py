@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
-
 
 def _kernel(a_ref, b_ref, y_ref, h_ref, *, bs: int):
     sj = pl.program_id(2)
@@ -38,26 +36,34 @@ def _kernel(a_ref, b_ref, y_ref, h_ref, *, bs: int):
 
 
 def rglru_scan(a, b, *, block_seq: int = 128, block_w: int = 512,
-               interpret: bool = True):
-    """a, b: (B, S, W) float32. Returns h: (B, S, W)."""
+               interpret: bool = False):
+    """a, b: (B, S, W) float32. Returns h: (B, S, W).
+
+    Any S: a length above the sequence block that is not a multiple of it is
+    right-padded to whole blocks (padding never reaches earlier steps) and
+    the padded steps are dropped."""
     B, S, W = a.shape
     bs = min(block_seq, S)
     bw = min(block_w, W)
-    assert S % bs == 0 and W % bw == 0, (S, bs, W, bw)
+    assert W % bw == 0, (W, bw)
+    Sp = -(-S // bs) * bs
+    if Sp != S:
+        pad = ((0, 0), (0, Sp - S), (0, 0))
+        a, b = jnp.pad(a, pad), jnp.pad(b, pad)
 
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs),
-        grid=(B, W // bw, S // bs),
+        grid=(B, W // bw, Sp // bs),
         in_specs=[
             pl.BlockSpec((1, bs, bw), lambda bi, wj, sj: (bi, sj, wj)),
             pl.BlockSpec((1, bs, bw), lambda bi, wj, sj: (bi, sj, wj)),
         ],
         out_specs=pl.BlockSpec((1, bs, bw), lambda bi, wj, sj: (bi, sj, wj)),
-        out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Sp, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="rglru_scan",
     )(a, b)
-    return out
+    return out[:, :S]

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x input-shape) combination
 on the production mesh, print memory/cost analysis, and record the roofline
 inputs. No real arrays are ever allocated (ShapeDtypeStruct in, AOT out).
@@ -8,10 +5,14 @@ inputs. No real arrays are ever allocated (ShapeDtypeStruct in, AOT out).
 Usage:
   python -m repro.launch.dryrun --arch yi-9b --shape train_4k --mesh pod1
   python -m repro.launch.dryrun --all --mesh pod1 --out experiments/dryrun
+
+``main()`` gives the CPU backend 512 virtual devices (``XLA_FLAGS``) before
+the first device use; importing this module changes no process state.
 """
 import argparse
 import functools
 import json
+import os
 import time
 import traceback
 
@@ -233,6 +234,7 @@ def main() -> None:
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     combos = ([(a, s) for a in list_archs() for s in SHAPES]
               if args.all else [(args.arch, args.shape)])

@@ -4,22 +4,26 @@ Serves simulated camera streams on the continuous-batching engine first (the
 measurement phase — the paper's empirical profiling step), then plans the
 fleet with the resource manager from the *measured* per-stream tokens/sec
 and reports cost, throughput, and SLO attainment. CPU-sized by default
-(reduced configs); the same flow drives full configs on real slices.
+(reduced configs, f32 weights); ``--full`` serves the published widths in
+bf16 on one chip, with the architecture's Pallas kernels on TPU:
+
+  PYTHONPATH=src python -m repro.launch.serve --full
 """
 from __future__ import annotations
 
 import argparse
 import json
+import time
 
 import jax
 import jax.numpy as jnp
 
 import numpy as np
 
-from repro.core.tpu_catalog import (LLMStream, plan_tpu_fleet,
-                                    streams_from_measured)
+from repro.core.tpu_catalog import plan_tpu_fleet, streams_from_measured
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
-from repro.models.config import get_config, list_archs
+from repro.models.config import ArchConfig, get_config, list_archs
 from repro.serving import (ContinuousBatchingEngine, Request, ServingEngine,
                            StreamSimulator)
 
@@ -39,12 +43,23 @@ def _warmup(eng, prompt_len: int, new_tokens: int) -> None:
     eng.reset_stats()
 
 
+_init_params = jax.jit(M.init_params, static_argnums=(0, 2))
+
+
+def init_weights(cfg: ArchConfig, *, reduced: bool):
+    """Random weights from ``PRNGKey(0)``: f32 for the reduced CPU configs,
+    bf16 at full width. The init runs under jit, so full-width weights are
+    produced in bf16 directly and never held in f32."""
+    dtype = jnp.float32 if reduced else jnp.bfloat16
+    return _init_params(cfg, jax.random.PRNGKey(0), dtype)
+
+
 def serve(arch: str = "olmo-1b", *, n_streams: int = 4, fps: float = 2.0,
           seconds: int = 3, reduced: bool = True,
-          dryrun_dir: str | None = None, engine: str = "continuous") -> dict:
-    # 1) serve the streams (reduced config on CPU) and measure throughput
+          engine: str = "continuous") -> dict:
+    # 1) serve the streams and measure throughput
     cfg = get_config(arch, reduced=reduced)
-    params = M.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    params = init_weights(cfg, reduced=reduced)
     if engine == "continuous":
         eng = ContinuousBatchingEngine(cfg, params, max_slots=8,
                                        cache_len=128)
@@ -52,7 +67,9 @@ def serve(arch: str = "olmo-1b", *, n_streams: int = 4, fps: float = 2.0,
         eng = ServingEngine(cfg, params, max_batch=8, cache_len=128)
     else:
         raise ValueError(engine)
+    t0 = time.monotonic()
     _warmup(eng, prompt_len=32, new_tokens=8)
+    warmup_s = time.monotonic() - t0
     sim = StreamSimulator(eng, prompt_len=32, new_tokens=8)
     done = []
     for t in range(seconds):
@@ -60,21 +77,24 @@ def serve(arch: str = "olmo-1b", *, n_streams: int = 4, fps: float = 2.0,
         done.extend(eng.drain())
 
     # 2) per-stream measured rates feed the packing machinery (the paper's
-    # profile-then-pack loop); streams that served no frames fall back to
-    # their nominal fps x tokens-per-frame target
+    # profile-then-pack loop, with closed-form per-stream requirements);
+    # streams that served no frames fall back to their nominal
+    # fps x tokens-per-frame target
     measured = eng.measured_rates()
     for i in range(n_streams):
         measured.setdefault(f"cam-{i}", fps * 8)
 
     streams = streams_from_measured(arch, measured)
-    plans = {s: plan_tpu_fleet(streams, dryrun_dir=dryrun_dir, strategy=s)
+    plans = {s: plan_tpu_fleet(streams, strategy=s)
              for s in ("per-stream", "uniform-big", "packed")}
     packed, per_stream = plans["packed"], plans["per-stream"]
     savings = 1.0 - packed["hourly_cost"] / per_stream["hourly_cost"]
     out = {
         "arch": arch,
         "engine": engine,
+        "warmup_s": round(warmup_s, 2),
         "frames_served": len(done),
+        "tokens_per_frame": sorted({len(r.output) for r in done}),
         "tokens_per_s": round(eng.throughput_tokens_per_s(), 1),
         "measured_stream_tokens_per_s": {k: round(v, 1)
                                          for k, v in sorted(measured.items())},
@@ -96,10 +116,12 @@ def main() -> None:
     ap.add_argument("--seconds", type=int, default=3)
     ap.add_argument("--engine", choices=("continuous", "static"),
                     default="continuous")
-    ap.add_argument("--dryrun-dir", default=None)
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="published widths in bf16 (default: reduced, f32)")
     args = ap.parse_args()
+    enable_compile_cache()
     out = serve(args.arch, n_streams=args.streams, fps=args.fps,
-                seconds=args.seconds, dryrun_dir=args.dryrun_dir,
+                seconds=args.seconds, reduced=args.reduced,
                 engine=args.engine)
     print(json.dumps(out, indent=2))
 

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.checkpoint import save_checkpoint
 from repro.data.pipeline import InputShape, SHAPES, make_batch
 from repro.launch import sharding as SH
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.models import model as M
 from repro.models import steps as ST
@@ -80,6 +81,7 @@ def main() -> None:
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--mesh", choices=["smoke", "pod1", "pod2"], default="smoke")
     args = ap.parse_args()
+    enable_compile_cache()
     mesh = (make_smoke_mesh() if args.mesh == "smoke"
             else make_production_mesh(multi_pod=args.mesh == "pod2"))
     rec = train(args.arch, reduced=args.reduced, steps=args.steps,

@@ -29,10 +29,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ops import on_tpu
 from repro.models import model as M
 from repro.models.config import ArchConfig
 from repro.models.steps import (make_jitted_decode, make_jitted_prefill,
                                 make_jitted_prefill_into_slot)
+
+
+def serving_options() -> M.ModelOptions:
+    """Default execution options of both engines: no remat, and the
+    architecture's Pallas kernels on a TPU backend (the ``jnp`` reference
+    path everywhere else)."""
+    return M.ModelOptions(remat=False, use_kernels=on_tpu())
 
 
 @dataclasses.dataclass
@@ -156,7 +164,7 @@ class ServingEngine(_EngineStatsMixin):
         self.params = params
         self.max_batch = max_batch
         self.cache_len = cache_len
-        self.opts = opts or M.ModelOptions(remat=False)
+        self.opts = opts or serving_options()
         self.queue: list[Request] = []
         self._prefill = make_jitted_prefill(cfg, self.opts, cache_len)
         self._decode = make_jitted_decode(cfg, self.opts)
@@ -243,7 +251,7 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
         self.params = params
         self.max_slots = max_slots
         self.cache_len = cache_len
-        self.opts = opts or M.ModelOptions(remat=False)
+        self.opts = opts or serving_options()
         self.queue: list[Request] = []
         self._prefill_slot = make_jitted_prefill_into_slot(
             cfg, self.opts, cache_len)

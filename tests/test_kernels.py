@@ -22,6 +22,8 @@ def _tol(dtype):
     (1, 256, 4, 64, 1, 256, True, 64),     # MQA sliding window
     (2, 128, 4, 64, 4, 256, True, 0),      # decode-ish: T > S
     (1, 128, 2, 32, 2, 128, False, 0),     # encoder (bidirectional)
+    (1, 200, 4, 64, 1, 200, True, 64),     # ragged: padded to whole blocks
+    (1, 100, 2, 32, 2, 100, False, 0),     # ragged encoder: padded keys masked
     pytest.param(1, 512, 8, 128, 2, 512, True, 128,    # bigger window
                  marks=pytest.mark.slow),
 ])
@@ -29,7 +31,8 @@ def test_flash_attention(dtype, B, S, H, hd, K, T, causal, window):
     q = jnp.asarray(RNG.standard_normal((B, S, H, hd)), dtype)
     k = jnp.asarray(RNG.standard_normal((B, T, K, hd)), dtype)
     v = jnp.asarray(RNG.standard_normal((B, T, K, hd)), dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window, bq=64, bk=64)
+    out = flash_attention(q, k, v, causal=causal, window=window, bq=64, bk=64,
+                          interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -48,7 +51,7 @@ def test_ssd_scan_kernel(b, s, h, p, g, n, L):
     A = jnp.asarray(-RNG.uniform(0.5, 2.0, (h,)), jnp.float32)
     B = jnp.asarray(RNG.standard_normal((b, s, g, n)), jnp.float32)
     C = jnp.asarray(RNG.standard_normal((b, s, g, n)), jnp.float32)
-    out = ssd_scan(x, dt, A, B, C, L)
+    out = ssd_scan(x, dt, A, B, C, L, interpret=True)
     want = ref.ssd_scan_ref(x, dt, A, B, C, L)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
@@ -74,12 +77,13 @@ def test_ssd_chunked_equals_sequential():
     pytest.param(2, 128, 512, 64, 128, marks=pytest.mark.slow),
     pytest.param(1, 256, 256, 128, 256, marks=pytest.mark.slow),
     (3, 64, 128, 64, 128),
+    (2, 200, 128, 64, 128),                # ragged: padded to whole blocks
     pytest.param(1, 512, 1024, 128, 512, marks=pytest.mark.slow),
 ])
 def test_rglru_scan_kernel(B, S, W, bs, bw):
     a = jnp.asarray(RNG.uniform(0.7, 0.999, (B, S, W)), jnp.float32)
     b = jnp.asarray(RNG.standard_normal((B, S, W)), jnp.float32)
-    out = rglru_scan(a, b, block_seq=bs, block_w=bw)
+    out = rglru_scan(a, b, block_seq=bs, block_w=bw, interpret=True)
     want = ref.rglru_scan_ref(a, b)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)

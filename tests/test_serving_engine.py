@@ -342,3 +342,42 @@ def test_tick_fractional_fps_accumulates_exactly():
     assert by_stream == {"half": 6, "quarter": 2}
     # the frame period is the deadline budget
     assert eng.requests[-1].deadline_s in (2.0, 4.0)
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "recurrentgemma-9b"])
+def test_kernel_engine_serves_prompt_off_the_block_grid(arch):
+    """With the Pallas kernels on (interpret mode here; compiled on a TPU
+    backend), the engine admits a 200-token prompt: longer than one 128-row
+    kernel block and not a multiple of it. Its tokens match the ``jnp``
+    path's."""
+    cfg, params = _setup(arch)
+    rng = np.random.default_rng(3)
+    reqs = [(rng.integers(0, cfg.vocab_size, n).astype(np.int32), 4)
+            for n in (200, PROMPT_LEN)]
+    outs = []
+    for use_kernels in (True, False):
+        eng = ContinuousBatchingEngine(
+            cfg, params, max_slots=2,
+            opts=M.ModelOptions(remat=False, use_kernels=use_kernels))
+        for i, (t, m) in enumerate(reqs):
+            eng.submit(Request(f"r{i}", t.copy(), max_new_tokens=m))
+        outs.append({r.request_id: r.output for r in eng.drain()})
+    assert set(outs[0]) == {"r0", "r1"}
+    for k in outs[0]:
+        np.testing.assert_array_equal(outs[0][k], outs[1][k])
+
+
+def test_serve_reduced_end_to_end():
+    """``repro.launch.serve.serve`` at the reduced config: every frame
+    request of 4 streams x 2 fps x 3 s answered with its 8 tokens, measured
+    rates for every stream, and the three fleet plans."""
+    from repro.launch.serve import serve
+    out = serve("olmo-1b", reduced=True)
+    assert out["frames_served"] == 24
+    assert out["tokens_per_frame"] == [8]
+    assert out["serving_report"]["requests"] == 24
+    assert out["tokens_per_s"] > 0
+    assert sorted(out["measured_stream_tokens_per_s"]) == [
+        f"cam-{i}" for i in range(4)]
+    assert set(out["fleet_plans"]) == {"per-stream", "uniform-big", "packed"}
+    assert all(p["hourly_cost"] > 0 for p in out["fleet_plans"].values())

@@ -116,3 +116,14 @@ def test_sharded_train_step_runs_on_smoke_mesh():
         batch = make_batch(cfg, shape, seed=0)
         _, metrics = step(state, batch)
         assert np.isfinite(float(metrics["loss"]))
+
+
+def test_importing_dryrun_changes_no_process_state():
+    """The 512-device override belongs to dryrun's main(), not its import."""
+    import importlib
+    import os
+
+    import repro.launch.dryrun as dryrun
+    before = os.environ.get("XLA_FLAGS")
+    importlib.reload(dryrun)
+    assert os.environ.get("XLA_FLAGS") == before
