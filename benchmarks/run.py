@@ -1,9 +1,8 @@
 """Benchmark harness — one module per paper table/figure (+ beyond-paper).
 
-Prints ``name,us_per_call,derived`` CSV per the repository convention, and a
-roofline summary (from the dry-run artifacts) at the end. ``--only <suite>``
-runs a single suite (e.g. ``--only fleet_sim`` as a CI smoke job) instead of
-the full sweep; ``--list`` shows the suite keys.
+Prints ``name,us_per_call,derived`` CSV per the repository convention.
+``--only <suite>`` runs a single suite (e.g. ``--only fleet_sim`` as a CI
+smoke job) instead of the full sweep; ``--list`` shows the suite keys.
 """
 from __future__ import annotations
 
@@ -90,18 +89,6 @@ def main() -> None:
                 mismatches += 1
             print(f"{row['name']},{row['us_per_call']:.1f},"
                   f"\"{row['derived']}{tail}\"")
-
-    # roofline summary appendix (not CSV — table form; full sweeps only)
-    if args.only is None:
-        from benchmarks import roofline
-        try:
-            rows = roofline.full_table("pod1")
-            if rows:
-                print("\n# --- roofline (single pod, 256 chips; "
-                      "full table in EXPERIMENTS.md) ---")
-                print(roofline.format_table(rows))
-        except Exception as e:                  # dry-run not executed yet
-            print(f"# roofline skipped: {e}")
 
     if mismatches:
         print(f"# WARNING: {mismatches} cells mismatch the paper")

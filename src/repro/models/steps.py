@@ -4,7 +4,6 @@ dry-run lowers."""
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Optional
 
 import jax
@@ -121,25 +120,37 @@ def prefill_into_slot_step(params: Pytree, cache: Pytree, batch: dict, slot,
     return logits[0], M.insert_cache_slot(cache, one, slot)
 
 
+def _jit_as(step, fn, **jit_kwargs):
+    """``jax.jit(fn)`` under ``step``'s name: the program lowers and shows
+    in a device trace as ``jit_<step name>`` (a ``functools.partial``
+    lowers as ``jit__unknown``)."""
+    fn.__name__ = fn.__qualname__ = step.__name__
+    return jax.jit(fn, **jit_kwargs)
+
+
 def make_jitted_train_step(cfg: ArchConfig, opts: M.ModelOptions,
                            topts: TrainOptions, **jit_kwargs):
-    f = functools.partial(train_step, cfg=cfg, opts=opts, topts=topts)
-    return jax.jit(f, **jit_kwargs)
+    def f(state, batch):
+        return train_step(state, batch, cfg, opts, topts)
+    return _jit_as(train_step, f, **jit_kwargs)
 
 
 def make_jitted_prefill(cfg: ArchConfig, opts: M.ModelOptions, cache_len: int,
                         **jit_kwargs):
-    f = functools.partial(prefill_step, cfg=cfg, opts=opts, cache_len=cache_len)
-    return jax.jit(f, **jit_kwargs)
+    def f(params, batch):
+        return prefill_step(params, batch, cfg, opts, cache_len)
+    return _jit_as(prefill_step, f, **jit_kwargs)
 
 
 def make_jitted_decode(cfg: ArchConfig, opts: M.ModelOptions, **jit_kwargs):
-    f = functools.partial(decode_step, cfg=cfg, opts=opts)
-    return jax.jit(f, **jit_kwargs)
+    def f(params, cache, batch):
+        return decode_step(params, cache, batch, cfg, opts)
+    return _jit_as(decode_step, f, **jit_kwargs)
 
 
 def make_jitted_prefill_into_slot(cfg: ArchConfig, opts: M.ModelOptions,
                                   cache_len: int, **jit_kwargs):
-    f = functools.partial(prefill_into_slot_step, cfg=cfg, opts=opts,
-                          cache_len=cache_len)
-    return jax.jit(f, **jit_kwargs)
+    def f(params, cache, batch, slot):
+        return prefill_into_slot_step(params, cache, batch, slot, cfg, opts,
+                                      cache_len)
+    return _jit_as(prefill_into_slot_step, f, **jit_kwargs)

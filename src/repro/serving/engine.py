@@ -18,16 +18,36 @@ Two engines (see DESIGN.md for the design rationale):
 
 The measured tokens/sec feeds core/tpu_catalog.py, which runs the paper's
 packing machinery over TPU slice types instead of EC2 instances.
+
+The continuous engine instruments itself. Host spans
+(``jax.profiler.TraceAnnotation``, recorded only while a profiler trace
+runs, so they share the device trace's clock) mark each part of a step::
+
+    serving.step (queued, active)
+      serving.schedule                 EDF sort, choice of free slots
+      serving.admit (request_id, slot, prompt_tokens)
+        serving.prefill.launch         device inputs, program call
+        serving.prefill.wait           first token to the host
+      serving.decode.launch (active)
+      serving.decode.wait              next tokens to the host
+      serving.retire (request_id)
+    host.gc (generation)               a Python garbage collection
+
+and always-on counters, under ``report()["host"]``, sum the same phases.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
 import time
+import weakref
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels.ops import on_tpu
 from repro.models import model as M
@@ -53,6 +73,9 @@ class Request:
     deadline_s: float = float("inf")   # per-frame latency budget (1/fps)
     output: Optional[np.ndarray] = None
     finish_t: float = 0.0
+    # continuous engine, same clock as enqueue_t; nan until they happen
+    admit_t: float = float("nan")        # admission starts
+    first_token_t: float = float("nan")  # the prefill's token on the host
 
     @property
     def deadline_t(self) -> float:
@@ -153,6 +176,37 @@ class _EngineStatsMixin:
                     out[sid] = delta / span
         self._rate_snapshot = (wall, dict(self._stream_tokens))
         return out
+
+
+class _GcPauses:
+    """``gc.callbacks`` hook: a ``host.gc`` span around every collection
+    of the process, and its pause counted by every live engine that
+    watches. Installed once per process, by the first engine."""
+
+    def __init__(self):
+        self.engines: weakref.WeakSet = weakref.WeakSet()
+        self._open: Optional[tuple[float, TraceAnnotation]] = None
+
+    def watch(self, engine) -> None:
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+        self.engines.add(engine)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            span = TraceAnnotation("host.gc", generation=info["generation"])
+            span.__enter__()
+            self._open = (time.monotonic(), span)
+        elif self._open is not None:
+            t0, span = self._open
+            self._open = None
+            span.__exit__(None, None, None)
+            pause = time.monotonic() - t0
+            for eng in self.engines:
+                eng._count_gc(pause)
+
+
+_GC_PAUSES = _GcPauses()
 
 
 class ServingEngine(_EngineStatsMixin):
@@ -268,6 +322,8 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
         self._init_stream_stats()
         self.stats = {"requests": 0, "tokens_generated": 0, "prefills": 0,
                       "decode_steps": 0, "wall_s": 0.0}
+        self._zero_host()
+        _GC_PAUSES.watch(self)
 
     # -- queue ---------------------------------------------------------------
 
@@ -286,30 +342,46 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
 
     # -- engine loop ---------------------------------------------------------
 
+    @contextlib.contextmanager
+    def _phase(self, counter: str, name: str, **args):
+        """A host span named ``name`` whose duration is added to the host
+        counter ``counter``."""
+        t0 = time.monotonic()
+        with TraceAnnotation(name, **args):
+            yield
+        self._host[counter] += time.monotonic() - t0
+
     def _admit(self, req: Request, slot: int) -> None:
-        tokens = jnp.asarray(req.tokens[None, :], jnp.int32)
-        logits, self.cache = self._prefill_slot(
-            self.params, self.cache, {"tokens": tokens},
-            jnp.asarray(slot, jnp.int32))
-        first = int(jnp.argmax(logits, -1))
-        self._slot_req[slot] = req
-        self._slot_out[slot] = [first]
-        self._slot_pos[slot] = len(req.tokens)
-        self._pending[slot] = first
-        self.stats["prefills"] += 1
-        self.stats["tokens_generated"] += 1
-        self._count_stream_token(req)
+        req.admit_t = time.monotonic()
+        with TraceAnnotation("serving.admit", request_id=req.request_id,
+                             slot=slot, prompt_tokens=len(req.tokens)):
+            with self._phase("launch_s", "serving.prefill.launch"):
+                tokens = jnp.asarray(req.tokens[None, :], jnp.int32)
+                logits, self.cache = self._prefill_slot(
+                    self.params, self.cache, {"tokens": tokens},
+                    jnp.asarray(slot, jnp.int32))
+            with self._phase("wait_s", "serving.prefill.wait"):
+                first = int(jnp.argmax(logits, -1))
+            req.first_token_t = time.monotonic()
+            self._slot_req[slot] = req
+            self._slot_out[slot] = [first]
+            self._slot_pos[slot] = len(req.tokens)
+            self._pending[slot] = first
+            self.stats["prefills"] += 1
+            self.stats["tokens_generated"] += 1
+            self._count_stream_token(req)
 
     def _retire(self, slot: int) -> Request:
         req = self._slot_req[slot]
-        req.output = np.asarray(self._slot_out[slot], np.int32)
-        req.finish_t = time.monotonic()
-        self._latencies.append(req.latency_s)
-        if req.latency_s <= req.deadline_s:
-            self._slo_hits += 1
-        self._slot_req[slot] = None
-        self._slot_out[slot] = []
-        self.stats["requests"] += 1
+        with TraceAnnotation("serving.retire", request_id=req.request_id):
+            req.output = np.asarray(self._slot_out[slot], np.int32)
+            req.finish_t = time.monotonic()
+            self._latencies.append(req.latency_s)
+            if req.latency_s <= req.deadline_s:
+                self._slo_hits += 1
+            self._slot_req[slot] = None
+            self._slot_out[slot] = []
+            self.stats["requests"] += 1
         return req
 
     def step(self) -> list[Request]:
@@ -319,42 +391,47 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
         t0 = time.monotonic()
         clock0 = self.stats["wall_s"]
         done: list[Request] = []
-
-        # 1) admission, earliest deadline first
-        if self.queue:
-            self.queue.sort(key=lambda r: r.deadline_t)
-            for slot in range(self.max_slots):
-                if not self.queue:
-                    break
-                if self._slot_req[slot] is not None:
-                    continue
-                self._admit(self.queue.pop(0), slot)
-                if len(self._slot_out[slot]) >= \
-                        self._slot_req[slot].max_new_tokens:
+        with TraceAnnotation("serving.step", queued=len(self.queue),
+                             active=len(self.active_slots())):
+            # 1) admission, earliest deadline first
+            with TraceAnnotation("serving.schedule"):
+                self.queue.sort(key=lambda r: r.deadline_t)
+                free = [s for s in range(self.max_slots)
+                        if self._slot_req[s] is None]
+                admit = list(zip(free, self.queue))
+                del self.queue[:len(admit)]
+            for slot, req in admit:
+                self._admit(req, slot)
+                if len(self._slot_out[slot]) >= req.max_new_tokens:
                     done.append(self._retire(slot))   # max_new_tokens == 1
 
-        # 2) one decode step for all active slots (free slots ride along and
-        # are overwritten by the next admission's prefill)
-        active = self.active_slots()
-        if active:
-            tok = jnp.asarray(self._pending, jnp.int32)
-            pos = jnp.asarray(self._slot_pos, jnp.int32)
-            logits, self.cache = self._decode(
-                self.params, self.cache, {"token": tok, "pos": pos})
-            nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
-            self.stats["decode_steps"] += 1
-            self._occupancy_sum += len(active) / self.max_slots
-            for s in active:
-                self._slot_pos[s] += 1
-                self._slot_out[s].append(int(nxt[s]))
-                self._pending[s] = nxt[s]
-                self.stats["tokens_generated"] += 1
-                self._count_stream_token(self._slot_req[s])
-                if len(self._slot_out[s]) >= self._slot_req[s].max_new_tokens:
-                    done.append(self._retire(s))
+            # 2) one decode step for all active slots (free slots ride along
+            # and are overwritten by the next admission's prefill)
+            active = self.active_slots()
+            if active:
+                with self._phase("launch_s", "serving.decode.launch",
+                                 active=len(active)):
+                    tok = jnp.asarray(self._pending, jnp.int32)
+                    pos = jnp.asarray(self._slot_pos, jnp.int32)
+                    logits, self.cache = self._decode(
+                        self.params, self.cache, {"token": tok, "pos": pos})
+                with self._phase("wait_s", "serving.decode.wait"):
+                    nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
+                self.stats["decode_steps"] += 1
+                self._occupancy_sum += len(active) / self.max_slots
+                for s in active:
+                    self._slot_pos[s] += 1
+                    self._slot_out[s].append(int(nxt[s]))
+                    self._pending[s] = nxt[s]
+                    self.stats["tokens_generated"] += 1
+                    self._count_stream_token(self._slot_req[s])
+                    if len(self._slot_out[s]) >= \
+                            self._slot_req[s].max_new_tokens:
+                        done.append(self._retire(s))
 
         self.stats["wall_s"] += time.monotonic() - t0
         self._mark_windows(clock0, self.stats["wall_s"])
+        self._host["steps"] += 1
         return done
 
     def drain(self) -> list[Request]:
@@ -371,6 +448,18 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
         self._latencies = []
         self._slo_hits = 0
         self._occupancy_sum = 0.0
+        self._zero_host()
+
+    def _zero_host(self) -> None:
+        self._host = {"steps": 0, "launch_s": 0.0, "wait_s": 0.0,
+                      "gc_pauses": 0, "gc_pause_s": 0.0,
+                      "gc_pause_max_s": 0.0}
+
+    def _count_gc(self, pause: float) -> None:
+        h = self._host
+        h["gc_pauses"] += 1
+        h["gc_pause_s"] += pause
+        h["gc_pause_max_s"] = max(h["gc_pause_max_s"], pause)
 
     def report(self) -> dict:
         """SLO attainment, latency percentiles, and slot occupancy — the
@@ -383,6 +472,16 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
         counters are zero — the report never raises. Contrast with
         ``Ledger.slo_attainment()``, which is vacuously 1.0 only under
         zero *demand* (nothing was asked for, so nothing was missed).
+
+        ``host`` holds the host counters since the last ``reset_stats()``:
+        ``steps`` and ``step_s`` (wall time inside ``step()``, the
+        ``wall_s`` of ``stats``), ``launch_s``
+        (building inputs and calling a program until the call returns),
+        ``wait_s`` (blocking until its tokens are on the host), ``host_s``
+        = ``step_s - launch_s - wait_s`` (scheduling, bookkeeping,
+        retirement: no program of the engine is in flight then, so the
+        device idles), and the process's garbage collections:
+        ``gc_pauses``, ``gc_pause_s``, ``gc_pause_max_s``.
         """
         lat = sorted(self._latencies)
         n = len(lat)
@@ -400,6 +499,9 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
             "p50_latency_s": pct(0.50),
             "p99_latency_s": pct(0.99),
             "slot_occupancy": (self._occupancy_sum / steps) if steps else 0.0,
+            "host": {**self._host, "step_s": self.stats["wall_s"],
+                     "host_s": self.stats["wall_s"] - self._host["launch_s"]
+                     - self._host["wait_s"]},
         }
 
 

@@ -90,14 +90,16 @@ class SeasonalForecaster:
         Columnar input resolves classes with one ``np.unique`` over the
         combined program/camera codes; the result is cached on the identity
         of the three arrays, so stable fleets (same ids, same codes object)
-        pay once. Object input takes the per-stream dict walk.
+        pay once. The cache holds the arrays themselves: an ``id()`` alone
+        could be reused by a later fleet's array once the first is freed.
+        Object input takes the per-stream dict walk.
         """
         if isinstance(streams, StreamColumns):
             cols = streams
-            key = (id(cols.ids), id(cols.program_codes),
-                   id(cols.camera_codes))
+            key = (cols.ids, cols.program_codes, cols.camera_codes)
             cached = self._idx_cache
-            if cached is not None and cached[0] == key:
+            if cached is not None and all(
+                    a is b for a, b in zip(cached[0], key)):
                 return cached[1], cached[2]
             pc = cols.program_codes
             cc = cols.camera_codes

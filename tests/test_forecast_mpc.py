@@ -362,3 +362,24 @@ def _check_constant_demand(fps):
     pred, known = fc.forecast_fps(51.0, streams)
     assert known.all()
     assert pred.tolist() == [s.fps for s in streams]
+
+
+def test_class_index_cache_is_not_fooled_by_a_reused_address():
+    """The columnar class cache keeps the arrays it was keyed on: a stable
+    fleet (same ids list, same camera codes) whose new program codes land
+    where the freed old codes were gets its own classes, not the old
+    ones."""
+    from repro.sim.demand import StreamColumns
+
+    ids, cams = [f"s{i}" for i in range(4)], np.full(4, -1, np.int64)
+    fc = SeasonalForecaster()
+    for _ in range(20):
+        codes = np.asarray([0, 0, 0, 0], np.int64)
+        assert fc._class_index(StreamColumns(
+            ids, np.ones(4), codes, ("a", "b"), cams, ()))[0] == [("a", "")]
+        del codes
+        codes = np.asarray([1, 1, 0, 1], np.int64)
+        keys, inv = fc._class_index(StreamColumns(
+            ids, np.ones(4), codes, ("a", "b"), cams, ()))
+        assert keys == [("a", ""), ("b", "")]
+        assert inv.tolist() == [1, 1, 0, 1]

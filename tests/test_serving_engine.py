@@ -1,6 +1,13 @@
 """Continuous-batching engine tests: greedy-token equivalence with the
 static engine, slot reuse within one drain, deadline (EDF) admission, the
-prefill-into-slot model step, and stats sanity."""
+prefill-into-slot model step, stats sanity, and the engine's own
+instrumentation (host counters, request stamps, profiler spans, program
+names)."""
+import gc
+import math
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,9 +15,14 @@ import pytest
 
 from repro.models import model as M
 from repro.models.config import get_config
-from repro.models.steps import make_jitted_prefill, make_jitted_prefill_into_slot
+from repro.models.steps import (make_jitted_decode, make_jitted_prefill,
+                                make_jitted_prefill_into_slot)
 from repro.serving import (ContinuousBatchingEngine, Request, ServingEngine,
                            StreamSimulator)
+
+# the checkout's root, for the benchmark's trace reader
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from bench import trace as tr  # noqa: E402
 
 CACHE_LEN = 48
 PROMPT_LEN = 16
@@ -381,3 +393,191 @@ def test_serve_reduced_end_to_end():
         f"cam-{i}" for i in range(4)]
     assert set(out["fleet_plans"]) == {"per-stream", "uniform-big", "packed"}
     assert all(p["hourly_cost"] > 0 for p in out["fleet_plans"].values())
+
+
+# -- the engine's own instrumentation ------------------------------------------
+
+PHASES = ("launch_s", "wait_s")
+
+
+def _serve_steps(eng, cfg, n, seed=0, max_new=None):
+    """Submit ``n`` mixed requests and step until drained; yields after
+    every step."""
+    for i, (t, m) in enumerate(_mixed_requests(cfg, n, seed)):
+        eng.submit(Request(f"r{i}", t, max_new_tokens=max_new or m))
+    while eng.queue or eng.active_slots():
+        eng.step()
+        yield
+
+
+def test_host_counters_add_up_and_only_grow():
+    cfg, params = _setup()
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
+                                   cache_len=CACHE_LEN)
+    prev, steps = eng.report()["host"], 0
+    for _ in _serve_steps(eng, cfg, 5):
+        steps += 1
+        h = eng.report()["host"]
+        assert h["steps"] == steps
+        assert h["host_s"] + h["launch_s"] + h["wait_s"] == \
+            pytest.approx(h["step_s"], rel=1e-9, abs=1e-12)
+        assert h["host_s"] >= 0
+        for k in ("step_s", "gc_pauses", "gc_pause_s", "gc_pause_max_s",
+                  *PHASES):
+            assert h[k] >= prev[k], f"{k} decreased"
+        assert h["launch_s"] > prev["launch_s"]   # every step decodes
+        prev = h
+    assert steps > 1
+
+
+@pytest.mark.parametrize("served", [False, True])
+def test_host_counters_zero_when_nothing_served(served):
+    cfg, params = _setup()
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
+                                   cache_len=CACHE_LEN)
+    if served:
+        for _ in _serve_steps(eng, cfg, 3):
+            pass
+        gc.collect()
+        assert eng.report()["host"]["gc_pauses"] > 0
+        eng.reset_stats()
+    h = eng.report()["host"]
+    assert set(h) == {"steps", "step_s", "launch_s", "wait_s", "host_s",
+                      "gc_pauses", "gc_pause_s", "gc_pause_max_s"}
+    assert all(v == 0 for v in h.values()), h
+
+
+@pytest.mark.parametrize("max_new", [1, 4])
+def test_request_stamps_in_order(max_new):
+    """A one-token request retires in its admission step; the stamps still
+    run enqueue <= admission <= first token <= finish."""
+    cfg, params = _setup()
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
+                                   cache_len=CACHE_LEN)
+    reqs = [Request(f"r{i}", t, max_new_tokens=max_new)
+            for i, (t, _) in enumerate(_mixed_requests(cfg, 3))]
+    assert all(math.isnan(r.admit_t) and math.isnan(r.first_token_t)
+               for r in reqs)
+    for r in reqs:
+        eng.submit(r)
+    done = eng.drain()
+    assert len(done) == 3
+    for r in reqs:
+        assert r.enqueue_t <= r.admit_t <= r.first_token_t <= r.finish_t
+        assert len(r.output) == max_new
+
+
+def test_forced_collection_is_counted():
+    cfg, params = _setup()
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
+                                   cache_len=CACHE_LEN)
+    serving = _serve_steps(eng, cfg, 3)
+    next(serving)
+    before = eng.report()["host"]["gc_pauses"]
+    gc.collect()
+    for _ in serving:
+        pass
+    h = eng.report()["host"]
+    assert h["gc_pauses"] >= before + 1
+    assert 0 < h["gc_pause_max_s"] <= h["gc_pause_s"]
+    # one hook per process, however many engines
+    ContinuousBatchingEngine(cfg, params, max_slots=1, cache_len=CACHE_LEN)
+    assert sum(type(cb).__name__ == "_GcPauses" for cb in gc.callbacks) == 1
+
+
+@pytest.mark.parametrize("program", ["prefill_into_slot_step", "decode_step",
+                                     "prefill_step"])
+def test_step_programs_lower_under_their_names(program):
+    """The device trace names a program after its lowered module, so each
+    step program lowers as ``jit_<step>``, not ``jit__unknown``."""
+    cfg, params = _setup()
+    opts = M.ModelOptions(remat=False)
+    cache = M.init_cache(cfg, 2, CACHE_LEN, jnp.float32, opts)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    lowered = {
+        "prefill_into_slot_step": lambda: make_jitted_prefill_into_slot(
+            cfg, opts, CACHE_LEN).lower(params, cache,
+                                        {"tokens": i32(1, PROMPT_LEN)}, i32()),
+        "decode_step": lambda: make_jitted_decode(cfg, opts).lower(
+            params, cache, {"token": i32(2), "pos": i32(2)}),
+        "prefill_step": lambda: make_jitted_prefill(
+            cfg, opts, CACHE_LEN).lower(params, {"tokens": i32(2, PROMPT_LEN)}),
+    }[program]()
+    assert lowered.as_text().startswith(f"module @jit_{program} ")
+
+
+# parent span of each engine span, as the engine module's docstring lists it
+PARENT = {"serving.schedule": "serving.step", "serving.admit": "serving.step",
+          "serving.decode.launch": "serving.step",
+          "serving.decode.wait": "serving.step",
+          "serving.retire": "serving.step",
+          "serving.prefill.launch": "serving.admit",
+          "serving.prefill.wait": "serving.admit"}
+
+
+def _host_spans(planes) -> list:
+    return sorted((ev for name, lines in planes.items()
+                   if name.startswith("/host") for evs in lines.values()
+                   for ev in evs
+                   if ev.name.startswith("serving.") or ev.name == "host.gc"),
+                  key=lambda ev: (ev.start, -ev.end))
+
+
+def _parent(spans, ev):
+    """The innermost ``serving.*`` span that holds ``ev``."""
+    best = None
+    for p in spans:
+        if p.start > ev.start:
+            break
+        if p is not ev and p.name.startswith("serving.") and p.end >= ev.end:
+            best = p
+    return best
+
+
+def test_spans_under_the_profiler_match_the_counters(tmp_path):
+    """Served under ``jax.profiler.trace`` on the CPU: one ``serving.step``
+    per step and one ``serving.admit`` per admission with its request id,
+    the spans nest as documented, each phase's summed spans agree with its
+    counter, and a forced collection shows as ``host.gc``."""
+    cfg, params = _setup()
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
+                                   cache_len=CACHE_LEN)
+    for _ in _serve_steps(eng, cfg, 2, seed=7):      # compile outside
+        pass
+    eng.reset_stats()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        for k, _ in enumerate(_serve_steps(eng, cfg, 5)):
+            if k == 1:
+                gc.collect()
+    h = eng.report()["host"]
+    spans = _host_spans(tr.load(tr.find_xplane(str(tmp_path))))
+    by = {}
+    for ev in spans:
+        by.setdefault(ev.name, []).append(ev)
+
+    assert len(by["serving.step"]) == h["steps"] > 0
+    assert sorted(ev.stats["request_id"] for ev in by["serving.admit"]) == \
+        [f"r{i}" for i in range(5)]
+    assert sorted(ev.stats["request_id"] for ev in by["serving.retire"]) == \
+        [f"r{i}" for i in range(5)]
+    assert all(ev.stats["prompt_tokens"] == PROMPT_LEN
+               for ev in by["serving.admit"])
+    assert {ev.stats["generation"] for ev in by["host.gc"]} >= {2}
+    for ev in spans:
+        parent = _parent(spans, ev)
+        if ev.name == "host.gc":
+            continue
+        assert (parent.name if parent else None) == PARENT.get(ev.name), \
+            (ev.name, ev.stats)
+
+    def total(*names):
+        return sum(ev.end - ev.start for n in names for ev in by[n])
+
+    for counter, names in (
+            ("step_s", ("serving.step",)),
+            ("launch_s", ("serving.prefill.launch", "serving.decode.launch")),
+            ("wait_s", ("serving.prefill.wait", "serving.decode.wait"))):
+        assert total(*names) == pytest.approx(
+            h[counter], rel=0.05, abs=1e-3), counter
