@@ -26,14 +26,15 @@ runs, so they share the device trace's clock) mark each part of a step::
     serving.step (queued, active)
       serving.schedule                 EDF sort, choice of free slots
       serving.admit (request_id, slot, prompt_tokens)
-        serving.prefill.launch         device inputs, program call
-        serving.prefill.wait           first token to the host
-      serving.decode.launch (active)
-      serving.decode.wait              next tokens to the host
+        serving.prefill.launch         device inputs, program call, the
+                                       first token into the token vector
+      serving.decode.launch (active)   device inputs, program call, argmax
+      serving.decode.wait              earlier programs' tokens to the host
       serving.retire (request_id)
     host.gc (generation)               a Python garbage collection
 
-and always-on counters, under ``report()["host"]``, sum the same phases.
+and always-on counters, under ``report()["host"]`` and
+``report()["pipeline"]``, sum the same phases and count the read-backs.
 """
 from __future__ import annotations
 
@@ -54,6 +55,28 @@ from repro.models import model as M
 from repro.models.config import ArchConfig
 from repro.models.steps import (make_jitted_decode, make_jitted_prefill,
                                 make_jitted_prefill_into_slot)
+
+
+# Programs the continuous engine keeps dispatched and unread. The cache is
+# not donated, so each program in flight holds a whole cache of its own
+# until it has run; two queued behind a running one keep the device busy.
+MAX_IN_FLIGHT = 3
+
+
+@jax.jit
+def _greedy_tokens(logits):
+    """A decode step's greedy tokens: (slots, V) logits -> (slots,) int32,
+    the token vector that feeds the next decode."""
+    return jnp.argmax(logits, -1).astype(jnp.int32)
+
+
+@jax.jit
+def _seat_first_token(tokens, logits, slot):
+    """An admission's first token, greedy over its prefill's (V,) logits,
+    written into row ``slot`` of the token vector that feeds the next
+    decode. Returns (that vector, the token as a (1,) array)."""
+    first = jnp.argmax(logits, -1).astype(jnp.int32)
+    return tokens.at[slot].set(first), first[None]
 
 
 def serving_options() -> M.ModelOptions:
@@ -285,10 +308,23 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
     is a slot. Per step: (1) admit queued requests into free slots in
     earliest-deadline-first order — each admission prefills that one request
     and inserts its KV/state into the slot (steps.prefill_into_slot_step),
-    leaving the other slots' caches untouched; (2) run a single batched
-    decode step with per-slot positions; (3) retire any request that reached
-    its ``max_new_tokens``, freeing its slot for the next admission instead
-    of stalling until the whole batch drains.
+    leaving the other slots' caches untouched; (2) dispatch a single batched
+    decode step with per-slot positions, and free the slot of every request
+    whose last token that step computes, for the next admission instead of
+    stalling until the whole batch drains; (3) read the tokens of the
+    programs dispatched in earlier steps to the host, and retire the
+    requests whose last token arrived.
+
+    The tokens that feed the next decode stay on the device: the decode's
+    argmax writes them, and each admission writes its first token into its
+    slot's row. The host schedules by counts (positions, ``max_new_tokens``)
+    and never needs a token's value to dispatch, so it reads one step
+    behind while the device works through the next. A step that leaves no
+    slot active and nothing queued reads back everything still in flight,
+    so every request submitted has its ``output`` once the engine is idle.
+    Before it dispatches a program, the host reads back the oldest while
+    ``MAX_IN_FLIGHT`` are unread, which bounds the caches a burst of
+    admissions holds on the device.
 
     Greedy decoding is identical to the static engine's: the prefill's
     last-position argmax is the first generated token, and each decode step
@@ -314,8 +350,14 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
         self.cache = M.init_cache(cfg, max_slots, cache_len, dtype, self.opts)
         self._slot_req: list[Optional[Request]] = [None] * max_slots
         self._slot_pos = np.zeros(max_slots, np.int32)   # next write position
+        # the occupant's tokens on the host, and its tokens not yet dispatched
         self._slot_out: list[list[int]] = [[] for _ in range(max_slots)]
-        self._pending = np.zeros(max_slots, np.int32)    # next token to feed
+        self._slot_left = [0] * max_slots
+        # next token to feed each slot, on the device
+        self._next = jnp.zeros(max_slots, jnp.int32)
+        # programs whose tokens are not yet on the host, oldest first: their
+        # token array and (row, request, the request's host tokens) per row
+        self._inflight: list[tuple[jax.Array, list]] = []
         self._latencies: list[float] = []
         self._slo_hits = 0
         self._occupancy_sum = 0.0
@@ -351,46 +393,76 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
             yield
         self._host[counter] += time.monotonic() - t0
 
+    def _enqueue_read(self, tokens: jax.Array, rows: list) -> None:
+        """Keep a dispatched program's token array until its read-back, and
+        start its copy to the host."""
+        tokens.copy_to_host_async()
+        self._inflight.append((tokens, rows))
+
     def _admit(self, req: Request, slot: int) -> None:
         req.admit_t = time.monotonic()
         with TraceAnnotation("serving.admit", request_id=req.request_id,
                              slot=slot, prompt_tokens=len(req.tokens)):
             with self._phase("launch_s", "serving.prefill.launch"):
                 tokens = jnp.asarray(req.tokens[None, :], jnp.int32)
+                at = jnp.asarray(slot, jnp.int32)
                 logits, self.cache = self._prefill_slot(
-                    self.params, self.cache, {"tokens": tokens},
-                    jnp.asarray(slot, jnp.int32))
-            with self._phase("wait_s", "serving.prefill.wait"):
-                first = int(jnp.argmax(logits, -1))
-            req.first_token_t = time.monotonic()
+                    self.params, self.cache, {"tokens": tokens}, at)
+                self._next, first = _seat_first_token(self._next, logits, at)
+                out: list[int] = []
+                self._enqueue_read(first, [(0, req, out)])
             self._slot_req[slot] = req
-            self._slot_out[slot] = [first]
+            self._slot_out[slot] = out
+            self._slot_left[slot] = req.max_new_tokens - 1
             self._slot_pos[slot] = len(req.tokens)
-            self._pending[slot] = first
             self.stats["prefills"] += 1
-            self.stats["tokens_generated"] += 1
-            self._count_stream_token(req)
 
-    def _retire(self, slot: int) -> Request:
-        req = self._slot_req[slot]
+    def _read_back(self, keep: int, overlapped: bool) -> list[Request]:
+        """Bring the tokens of all but the newest ``keep`` programs in
+        flight to the host, oldest first; returns the requests whose last
+        token arrived. ``overlapped``: a later program is already
+        dispatched, so the device stays busy while the host waits here."""
+        n = len(self._inflight) - keep
+        if n <= 0:
+            return []
+        entries = self._inflight[:n]
+        del self._inflight[:n]
+        self._pipeline["readbacks"] += 1
+        self._pipeline["overlapped" if overlapped else "flushes"] += 1
+        done = []
+        for tokens, rows in entries:
+            with self._phase("wait_s", "serving.decode.wait"):
+                vals = np.asarray(tokens)
+            arrived = time.monotonic()
+            for row, req, out in rows:
+                out.append(int(vals[row]))
+                if len(out) == 1:
+                    req.first_token_t = arrived
+                self.stats["tokens_generated"] += 1
+                self._count_stream_token(req)
+                if len(out) == req.max_new_tokens:
+                    done.append(self._retire(req, out))
+        return done
+
+    def _retire(self, req: Request, out: list[int]) -> Request:
         with TraceAnnotation("serving.retire", request_id=req.request_id):
-            req.output = np.asarray(self._slot_out[slot], np.int32)
+            req.output = np.asarray(out, np.int32)
             req.finish_t = time.monotonic()
             self._latencies.append(req.latency_s)
             if req.latency_s <= req.deadline_s:
                 self._slo_hits += 1
-            self._slot_req[slot] = None
-            self._slot_out[slot] = []
             self.stats["requests"] += 1
         return req
 
     def step(self) -> list[Request]:
-        """One engine iteration: EDF admission into free slots, then one
-        batched decode step for every occupied slot. Returns the requests
-        completed this iteration."""
+        """One engine iteration: EDF admission into free slots, one batched
+        decode step for every occupied slot, then the read-back of the
+        programs of earlier steps. Returns the requests whose last token
+        reached the host in this call."""
         t0 = time.monotonic()
         clock0 = self.stats["wall_s"]
         done: list[Request] = []
+        dispatched = 0
         with TraceAnnotation("serving.step", queued=len(self.queue),
                              active=len(self.active_slots())):
             # 1) admission, earliest deadline first
@@ -401,33 +473,44 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
                 admit = list(zip(free, self.queue))
                 del self.queue[:len(admit)]
             for slot, req in admit:
+                done += self._read_back(MAX_IN_FLIGHT - 1, True)
                 self._admit(req, slot)
-                if len(self._slot_out[slot]) >= req.max_new_tokens:
-                    done.append(self._retire(slot))   # max_new_tokens == 1
+                dispatched += 1
+                if not self._slot_left[slot]:       # max_new_tokens == 1
+                    self._slot_req[slot] = None
 
             # 2) one decode step for all active slots (free slots ride along
-            # and are overwritten by the next admission's prefill)
+            # and are overwritten by the next admission's prefill); a slot
+            # is free once its last token's program is dispatched
             active = self.active_slots()
             if active:
+                done += self._read_back(MAX_IN_FLIGHT - 1, True)
+                dispatched += 1
                 with self._phase("launch_s", "serving.decode.launch",
                                  active=len(active)):
-                    tok = jnp.asarray(self._pending, jnp.int32)
-                    pos = jnp.asarray(self._slot_pos, jnp.int32)
+                    # a copy: on the CPU the device array may alias its
+                    # host buffer, and the positions advance before the
+                    # step runs
+                    pos = jnp.asarray(self._slot_pos.copy())
                     logits, self.cache = self._decode(
-                        self.params, self.cache, {"token": tok, "pos": pos})
-                with self._phase("wait_s", "serving.decode.wait"):
-                    nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
+                        self.params, self.cache,
+                        {"token": self._next, "pos": pos})
+                    self._next = _greedy_tokens(logits)
+                    self._enqueue_read(self._next, [
+                        (s, self._slot_req[s], self._slot_out[s])
+                        for s in active])
                 self.stats["decode_steps"] += 1
                 self._occupancy_sum += len(active) / self.max_slots
                 for s in active:
                     self._slot_pos[s] += 1
-                    self._slot_out[s].append(int(nxt[s]))
-                    self._pending[s] = nxt[s]
-                    self.stats["tokens_generated"] += 1
-                    self._count_stream_token(self._slot_req[s])
-                    if len(self._slot_out[s]) >= \
-                            self._slot_req[s].max_new_tokens:
-                        done.append(self._retire(s))
+                    self._slot_left[s] -= 1
+                    if not self._slot_left[s]:
+                        self._slot_req[s] = None
+
+            # 3) read back the programs of earlier steps, while this step's
+            # programs keep the device busy; all of them when it goes idle
+            idle = not self.queue and not self.active_slots()
+            done += self._read_back(0 if idle else dispatched, not idle)
 
         self.stats["wall_s"] += time.monotonic() - t0
         self._mark_windows(clock0, self.stats["wall_s"])
@@ -454,6 +537,7 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
         self._host = {"steps": 0, "launch_s": 0.0, "wait_s": 0.0,
                       "gc_pauses": 0, "gc_pause_s": 0.0,
                       "gc_pause_max_s": 0.0}
+        self._pipeline = {"readbacks": 0, "overlapped": 0, "flushes": 0}
 
     def _count_gc(self, pause: float) -> None:
         h = self._host
@@ -477,11 +561,15 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
         ``steps`` and ``step_s`` (wall time inside ``step()``, the
         ``wall_s`` of ``stats``), ``launch_s``
         (building inputs and calling a program until the call returns),
-        ``wait_s`` (blocking until its tokens are on the host), ``host_s``
-        = ``step_s - launch_s - wait_s`` (scheduling, bookkeeping,
-        retirement: no program of the engine is in flight then, so the
-        device idles), and the process's garbage collections:
+        ``wait_s`` (blocking until earlier programs' tokens are on the
+        host), ``host_s`` = ``step_s - launch_s - wait_s`` (scheduling,
+        bookkeeping, retirement), and the process's garbage collections:
         ``gc_pauses``, ``gc_pause_s``, ``gc_pause_max_s``.
+
+        ``pipeline`` counts the read-backs since the last ``reset_stats()``:
+        ``readbacks``, of which ``overlapped`` (a later program was already
+        dispatched, so the device had work while the host waited) and
+        ``flushes`` (the engine went idle and read everything in flight).
         """
         lat = sorted(self._latencies)
         n = len(lat)
@@ -502,6 +590,7 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
             "host": {**self._host, "step_s": self.stats["wall_s"],
                      "host_s": self.stats["wall_s"] - self._host["launch_s"]
                      - self._host["wait_s"]},
+            "pipeline": dict(self._pipeline),
         }
 
 

@@ -6,6 +6,7 @@ names)."""
 import gc
 import math
 import sys
+import time
 from pathlib import Path
 
 import jax
@@ -19,6 +20,7 @@ from repro.models.steps import (make_jitted_decode, make_jitted_prefill,
                                 make_jitted_prefill_into_slot)
 from repro.serving import (ContinuousBatchingEngine, Request, ServingEngine,
                            StreamSimulator)
+from repro.serving.engine import MAX_IN_FLIGHT
 
 # the checkout's root, for the benchmark's trace reader
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
@@ -76,15 +78,21 @@ def test_finished_slot_reused_within_drain():
     eng.submit(Request("long", toks(), max_new_tokens=8))
     eng.submit(Request("queued", toks(), max_new_tokens=4))
 
-    done1 = eng.step()        # admits short+long; short retires (2 tokens)
-    assert [r.request_id for r in done1] == ["short"]
+    # admits short+long and dispatches the decode of short's last (2nd)
+    # token, which frees its slot; that token is read one step later
+    done1 = eng.step()
+    assert done1 == []
     freed = eng._slot_req.index(None)
-    eng.step()                # queued admitted into the freed slot mid-decode
+    # queued admitted into the freed slot mid-decode, and short retires
+    done2 = eng.step()
+    returned = time.monotonic()
+    assert [r.request_id for r in done2] == ["short"]
+    assert done2[0].finish_t <= returned
     assert eng._slot_req[freed] is not None
     assert eng._slot_req[freed].request_id == "queued"
     assert eng._slot_req[1 - freed].request_id == "long"
 
-    done = done1 + eng.drain()
+    done = done1 + done2 + eng.drain()
     assert sorted(r.request_id for r in done) == ["long", "queued", "short"]
     assert eng.stats["prefills"] == 3
 
@@ -395,6 +403,140 @@ def test_serve_reduced_end_to_end():
     assert all(p["hourly_cost"] > 0 for p in out["fleet_plans"].values())
 
 
+# -- one-step-ahead dispatch ---------------------------------------------------
+
+NEW_TOKENS = (1, 8, 3, 1, 5, 2, 7, 4, 6, 1)   # every length from 1 to 8
+
+
+def _one_step_ahead_requests(cfg, seed):
+    rng = np.random.default_rng(seed)
+    return [Request(f"r{i}", rng.integers(0, cfg.vocab_size, PROMPT_LEN)
+                    .astype(np.int32), max_new_tokens=m)
+            for i, m in enumerate(NEW_TOKENS)]
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "mamba2-2.7b"])
+def test_pipelined_outputs_match_requests_run_one_at_a_time(arch):
+    """Admissions interleaved with decodes (two submissions per step into
+    three slots), tokens read one step behind: every request's tokens
+    equal those of the request run alone through the static engine."""
+    cfg, params = _setup(arch)
+    static = ServingEngine(cfg, params, max_batch=1, cache_len=CACHE_LEN)
+    want = {}
+    for r in _one_step_ahead_requests(cfg, 5):
+        static.submit(r)
+        (r,) = static.step()
+        want[r.request_id] = r.output
+
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=3,
+                                   cache_len=CACHE_LEN)
+    todo, got = _one_step_ahead_requests(cfg, 5), {}
+    while todo or eng.queue or eng.active_slots():
+        for r in todo[:2]:
+            eng.submit(r)
+        del todo[:2]
+        got.update((r.request_id, r.output) for r in eng.step())
+    assert set(got) == set(want)
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+
+
+def test_idle_step_leaves_every_request_finished():
+    """Waves of requests, one of only one-token requests (no decode at
+    all): after any step that leaves the queue empty and no slot active,
+    every submitted request has its output and its finish time."""
+    cfg, params = _setup()
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
+                                   cache_len=CACHE_LEN)
+    reqs = _one_step_ahead_requests(cfg, 6)
+    waves = [reqs[:3], [r for r in reqs[3:] if r.max_new_tokens == 1],
+             [r for r in reqs[3:] if r.max_new_tokens > 1]]
+    submitted, idle_steps = [], 0
+    for wave in waves:
+        for r in wave:
+            eng.submit(r)
+        submitted += wave
+        while eng.queue or eng.active_slots():
+            eng.step()
+            if not eng.queue and not eng.active_slots():
+                idle_steps += 1
+                for r in submitted:
+                    assert r.output is not None, r.request_id
+                    assert len(r.output) == r.max_new_tokens
+                    assert r.first_token_t <= r.finish_t
+    assert idle_steps == len(waves)
+    assert not eng._inflight
+
+
+def test_pipeline_counters():
+    cfg, params = _setup()
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
+                                   cache_len=CACHE_LEN)
+    for _ in _serve_steps(eng, cfg, 6):
+        pass
+    p = eng.report()["pipeline"]
+    assert p["readbacks"] == p["overlapped"] + p["flushes"]
+    assert p["overlapped"] > 0
+    assert p["flushes"] == 1          # one drain, one idle step
+    eng.reset_stats()
+    assert eng.report()["pipeline"] == {"readbacks": 0, "overlapped": 0,
+                                        "flushes": 0}
+
+
+def test_next_decode_dispatched_before_previous_is_read():
+    """While the engine is busy with at most one admission a step, decode
+    k's tokens reach the host only after decode k + 1 has been dispatched,
+    and the idle step reads the last. A burst of admissions never leaves
+    more than ``MAX_IN_FLIGHT`` programs unread, and every read made while
+    busy leaves a later program to run."""
+    cfg, params = _setup()
+    slots = 4
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=slots,
+                                   cache_len=CACHE_LEN)
+    dispatched, read, most = [0], [0], [0]
+    decode, prefill, read_back = eng._decode, eng._prefill_slot, eng._read_back
+
+    def counting_decode(*args):
+        dispatched[0] += 1
+        most[0] = max(most[0], len(eng._inflight) + 1)
+        return decode(*args)
+
+    def counting_prefill(*args):
+        most[0] = max(most[0], len(eng._inflight) + 1)
+        return prefill(*args)
+
+    def checked_read_back(keep, overlapped):
+        # a decode's token vector has a row per slot, a prefill's one row
+        entries = eng._inflight[:max(0, len(eng._inflight) - keep)]
+        read[0] += sum(tokens.shape == (slots,) for tokens, _ in entries)
+        if not overlapped:
+            assert read[0] == dispatched[0]
+        elif entries:
+            assert keep >= 1
+            if not burst:
+                assert read[0] < dispatched[0], (read[0], dispatched[0])
+        return read_back(keep, overlapped)
+
+    eng._decode, eng._prefill_slot = counting_decode, counting_prefill
+    eng._read_back = checked_read_back
+    # one submission a step into a pool that stays busy, then a burst
+    idle = lambda: not eng.queue and not eng.active_slots()
+    burst, todo, idle_steps = False, _one_step_ahead_requests(cfg, 8), 0
+    while todo or not idle():
+        if todo:
+            eng.submit(todo.pop(0))
+        eng.step()
+        idle_steps += idle()
+    assert dispatched[0] > len(NEW_TOKENS) and read[0] == dispatched[0]
+    burst = True      # four admissions at once: six programs unbounded
+    for _ in _serve_steps(eng, cfg, 8):
+        idle_steps += idle()
+    assert read[0] == dispatched[0]
+    assert most[0] == MAX_IN_FLIGHT
+    p = eng.report()["pipeline"]
+    assert p["overlapped"] > 0 and p["flushes"] == idle_steps
+
+
 # -- the engine's own instrumentation ------------------------------------------
 
 PHASES = ("launch_s", "wait_s")
@@ -449,8 +591,8 @@ def test_host_counters_zero_when_nothing_served(served):
 
 @pytest.mark.parametrize("max_new", [1, 4])
 def test_request_stamps_in_order(max_new):
-    """A one-token request retires in its admission step; the stamps still
-    run enqueue <= admission <= first token <= finish."""
+    """A one-token request needs no decode; the stamps still run
+    enqueue <= admission <= first token <= finish."""
     cfg, params = _setup()
     eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
                                    cache_len=CACHE_LEN)
@@ -511,8 +653,7 @@ PARENT = {"serving.schedule": "serving.step", "serving.admit": "serving.step",
           "serving.decode.launch": "serving.step",
           "serving.decode.wait": "serving.step",
           "serving.retire": "serving.step",
-          "serving.prefill.launch": "serving.admit",
-          "serving.prefill.wait": "serving.admit"}
+          "serving.prefill.launch": "serving.admit"}
 
 
 def _host_spans(planes) -> list:
@@ -578,6 +719,6 @@ def test_spans_under_the_profiler_match_the_counters(tmp_path):
     for counter, names in (
             ("step_s", ("serving.step",)),
             ("launch_s", ("serving.prefill.launch", "serving.decode.launch")),
-            ("wait_s", ("serving.prefill.wait", "serving.decode.wait"))):
+            ("wait_s", ("serving.decode.wait",))):
         assert total(*names) == pytest.approx(
             h[counter], rel=0.05, abs=1e-3), counter
