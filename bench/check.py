@@ -2,12 +2,13 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and holding the one with the
-most served tokens, is run through the plain reference (``bench.reference``)
-over each prompt with its served tokens. At each served position the gap is
-the reference's best logit minus the reference's logit of the token served.
-The widest gap over the sample is the number compared with the cell's
-limit. Greedy decoding serves the argmax, so a sound program reads a gap
-only where its rounding flips a near tie.
+most served tokens, is run through the plain reference (``bench.reference``,
+with the configuration's model module) over each prompt with its served
+tokens. At each served position the gap is the reference's best logit
+minus the reference's logit of the token served. The widest gap over the
+sample is the number compared with the cell's limit. Greedy decoding
+serves the argmax, so a sound program reads a gap only where its rounding
+flips a near tie.
 
 The control puts the reference computed in float8 in the program's place:
 at the same positions it takes the token the float8 logits put first and
@@ -43,11 +44,12 @@ def sample(records: list, seed: int, target: int = SAMPLE_TOKENS) -> list:
     return out
 
 
-def gaps(params, model: dict, records: list, prompt_len: int, max_new: int,
-         control: bool = False) -> dict:
+def gaps(params, mod, model: dict, records: list, prompt_len: int,
+         max_new: int, control: bool = False) -> dict:
     """Gaps of the served tokens (and of the control's tokens) of
-    ``records``. Every call has ``ROWS`` rows of the mix's longest
-    sequence, so one compile serves every run of a cell."""
+    ``records``, through the model module ``mod``. Every call has
+    ``ROWS`` rows of the mix's longest sequence, so one compile serves
+    every run of a cell."""
     served, ctrl = [], []
     max_len, width = prompt_len + max_new - 1, ROWS * max_new
     for b in range(0, len(records), ROWS):
@@ -65,12 +67,13 @@ def gaps(params, model: dict, records: list, prompt_len: int, max_new: int,
             want.append(out)
             k += n
         want = np.concatenate(want)
-        ref = np.asarray(reference.logits(params, model, toks, rows, cols))[:k]
+        ref = np.asarray(reference.logits(params, mod, model, toks, rows,
+                                          cols))[:k]
         best = ref.max(-1)
         served.append(best - ref[np.arange(k), want])
         if control:
-            low = np.asarray(reference.logits(params, model, toks, rows, cols,
-                                              quant="fp8"))[:k]
+            low = np.asarray(reference.logits(params, mod, model, toks, rows,
+                                              cols, quant="fp8"))[:k]
             ctrl.append(best - ref[np.arange(k), low.argmax(-1)])
     cat = lambda a: np.concatenate(a) if a else np.zeros(0)
     out = {"served": cat(served), "tokens": int(sum(len(a) for a in served))}

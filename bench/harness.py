@@ -11,9 +11,14 @@ belongs to it is found by name under ``bench/``:
 - ``cells/<cell>.json``       the cell's fixed load (``cameras``), the
   engine's slot pool sized to it (``max_slots``) and the limits of its
   comparison, and the same under ``rehearsal``;
+- ``models/<config>.py``      the model module: the weights in the
+  program's layout and the reference's equations (``bench.models``);
 - ``metrics/<metric>.py``     one reader per metric: ``read(run)`` returns
   a number, or ``None`` where it finds nothing to read;
 - ``costs/``, ``peaks.json``  operations and bytes per call, chip peaks.
+
+The trace's reduction keeps the device time of every Pallas kernel by its
+name, so a new kernel needs only its cost file and its reader.
 
 The run: weights from the seed on the device (``bench.weights``), the
 program's ``ContinuousBatchingEngine`` at the cell's slots and the
@@ -26,6 +31,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -35,6 +41,7 @@ import sys
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Optional
 
 import jax
@@ -50,7 +57,6 @@ from bench import trace as tr
 
 ROOT = Path(__file__).resolve().parents[1]
 CACHE_DIR = ROOT / ".jax_cache"
-KERNELS = ("flash_attention", "ssd_scan")
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
                   "/jax/core/compile/backend_compile_duration")
 
@@ -67,6 +73,7 @@ class Cell:
     mix: dict              # the traffic file
     load: dict             # the cell file
     metrics: dict          # metric name -> BENCHMARK.json entry
+    model_mod: ModuleType  # bench/models/<config>.py
 
     def sizes(self, rehearse: bool) -> dict:
         return self.config["rehearsal"] if rehearse else self.config
@@ -94,16 +101,34 @@ def load_cell(name: str) -> Cell:
     read = lambda p: json.loads((ROOT / p).read_text())
     return Cell(name, w, read(conf["file"]),
                 read(f"bench/traffic/{w['traffic']}.json"),
-                read(f"bench/cells/{name}.json"), metrics)
+                read(f"bench/cells/{name}.json"), metrics,
+                model_module(w["config"]))
+
+
+def _load(path: Path, prefix: str) -> ModuleType:
+    """The Python file ``path`` as a module, whatever dots and hyphens its
+    name holds."""
+    spec = importlib.util.spec_from_file_location(
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(name: str) -> Callable:
-    path = ROOT / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(ROOT / "bench" / "metrics" / f"{name}.py", "bench_metric_").read
+
+
+@functools.lru_cache(maxsize=None)
+def model_module(config: str) -> ModuleType:
+    """``bench/models/<config>.py``, loaded once per process: the jitted
+    weights and reference take the module as a static argument, so one
+    object means one compile."""
+    path = ROOT / "bench" / "models" / f"{config}.py"
+    if not path.is_file():
+        raise Refused(f"no model module {path.relative_to(ROOT)} for "
+                      f"configuration {config!r}")
+    return _load(path, "bench_model_")
 
 
 def program_config(arch: str, model: dict, rehearse: bool):
@@ -238,7 +263,8 @@ class RunData:
 def build(cell: Cell, seed: int, rehearse: bool):
     sizes = cell.sizes(rehearse)
     cfg = program_config(cell.config["arch"], sizes["model"], rehearse)
-    params = weights.make_weights(sizes["model"], seed, cell.config["dtype"])
+    params = weights.make_weights(cell.model_mod, sizes["model"], seed,
+                                  cell.config["dtype"])
     jax.block_until_ready(params)
     dtype = weights.DTYPES[cell.config["dtype"]]
     weights.check_layout(params, jax.eval_shape(
@@ -297,7 +323,7 @@ def run(cell: Cell, seed: int, seconds: float, *, trace: bool,
     gc.collect()
     if trace:
         path = tr.find_xplane(tdir)
-        reduced = tr.reduce(tr.load(path), KERNELS) if path else None
+        reduced = tr.reduce(tr.load(path)) if path else None
         if save_trace and path:
             shutil.copy(path, save_trace)
         shutil.rmtree(tdir, ignore_errors=True)
@@ -308,8 +334,8 @@ def run(cell: Cell, seed: int, seconds: float, *, trace: bool,
     # the comparison with the reference
     t_ref = time.monotonic()
     picked = check.sample(records, seed)
-    g = check.gaps(params, sizes["model"], picked, cell.mix["prompt_tokens"],
-                   cell.mix["output_tokens"][1])
+    g = check.gaps(params, cell.model_mod, sizes["model"], picked,
+                   cell.mix["prompt_tokens"], cell.mix["output_tokens"][1])
     # nothing to compare is as bad as the worst gap: finite, so the line
     # stays plain JSON
     widest = float(g["served"].max()) if g["tokens"] else 1e9

@@ -64,8 +64,9 @@ def calibrate_seed(cell, seed: int, seconds: float, rehearse: bool) -> dict:
     del eng
     gc.collect()
     picked = check.sample(recs, seed)
-    g = check.gaps(params, sizes["model"], picked, cell.mix["prompt_tokens"],
-                   cell.mix["output_tokens"][1], control=True)
+    g = check.gaps(params, cell.model_mod, sizes["model"], picked,
+                   cell.mix["prompt_tokens"], cell.mix["output_tokens"][1],
+                   control=True)
     return {"seed": seed, "requests": len(picked), "tokens": g["tokens"],
             "unfinished": sum(not r.finished for r in recs),
             "program_widest_gap": float(g["served"].max()),

@@ -1,6 +1,7 @@
-"""The trace reduction, on a hand-built trace and on a half-second trace of
+"""The trace reduction, on a hand-built trace and on half-second traces of
 ``olmo-1b.readout-steady`` recorded on a TPU v5e (``data/``)."""
 import gzip
+import hashlib
 import json
 from pathlib import Path
 
@@ -12,9 +13,20 @@ from bench.trace import Event
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).parent / "data" / "olmo_readout_0.5s.xplane.pb.gz"
-KERNELS = ("flash_attention", "ssd_scan")
 KERNEL_OP = ('%flash_attention.6 = bf16[16,384,128]{2,1,0} custom-call('
              'bf16[16,384,128]{2,1,0} %a), custom_call_target="tpu_custom_call"')
+OTHER_KERNEL_OP = ('%rms_norm.2 = bf16[384,128]{1,0} custom-call(bf16[384,128]'
+                   '{1,0} %h), custom_call_target="tpu_custom_call"')
+# sha256 of what the reduction read from each recorded trace when it kept
+# only the kernels it was given a list of (flash_attention, ssd_scan):
+# json.dumps of busy_s, window_s, idle_gaps, device_ops and each call's
+# (index, device_s), as ``_reduced_digest`` makes it
+REDUCED = {
+    "olmo_readout_0.5s.xplane.pb.gz":
+        "85fa1c277dbf4aceaf2e573f8e961c6d41336f75b4d2eacf9fdc250513312970",
+    "olmo_readout_spans_0.5s.xplane.pb.gz":
+        "ac6147a7e8e62cf59347d6370c1a451a6d398fa1e6da5493bbb9ee107dbe00c6",
+}
 
 
 def _synthetic():
@@ -27,7 +39,8 @@ def _synthetic():
     mods = [Event("jit__unknown(111)", 1.3, 2.9, {}),
             Event("jit__argmax(5)", 3.2, 3.3, {}),
             Event("jit__unknown(222)", 5.6, 7.0, {})]
-    ops = [Event("%fusion.1 = bf16[8] fusion(bf16[8] %x)", 1.3, 2.0, {}),
+    ops = [Event("%fusion.1 = bf16[8] fusion(bf16[8] %x)", 1.3, 1.8, {}),
+           Event(OTHER_KERNEL_OP, 1.8, 2.0, {}),
            Event(KERNEL_OP, 2.0, 2.5, {}),
            Event(KERNEL_OP, 2.5, 2.9, {}),
            Event("%argmax.1 = s32[] reduce(f32[8] %l)", 3.2, 3.3, {}),
@@ -38,14 +51,16 @@ def _synthetic():
 
 
 def test_synthetic_trace_reduces_exactly():
-    r = tr.reduce(_synthetic(), KERNELS)
+    r = tr.reduce(_synthetic())
     assert r["window_s"] == 10.0
     assert r["busy_s"] == pytest.approx(1.6 + 0.1 + 1.4)
     prefill, decode = r["calls"][0], r["calls"][1]
     assert (prefill.kind, prefill.launches) == ("prefill", 1)
     assert prefill.device_s == pytest.approx(1.6)
-    assert prefill.kernel_n == {"flash_attention": 2}
+    # every kernel by its name, with no list of names
+    assert prefill.kernel_n == {"flash_attention": 2, "rms_norm": 1}
     assert prefill.kernel_s["flash_attention"] == pytest.approx(0.9)
+    assert prefill.kernel_s["rms_norm"] == pytest.approx(0.2)
     assert (decode.kind, decode.launches, decode.kernel_n) == ("decode", 1, {})
     # gaps in falling length, labelled by the innermost open span
     assert [g[0] for g in r["idle_gaps"]] == [
@@ -67,7 +82,7 @@ def test_no_window_or_no_device_reads_nothing():
 
 @pytest.fixture(scope="module")
 def chip():
-    return tr.reduce(tr.load(gzip.decompress(DATA.read_bytes())), KERNELS)
+    return tr.reduce(tr.load(gzip.decompress(DATA.read_bytes())))
 
 
 def test_chip_trace_programs_and_kernels(chip):
@@ -87,6 +102,26 @@ def test_chip_trace_programs_and_kernels(chip):
     assert gaps == sorted(gaps, reverse=True)
     assert sum(gaps) <= chip["window_s"] - chip["busy_s"] + 1e-9
     assert len(chip["device_ops"]) == tr.TOP
+
+
+def _reduced_digest(r) -> str:
+    core = {"busy_s": r["busy_s"], "window_s": r["window_s"],
+            "idle_gaps": r["idle_gaps"], "device_ops": r["device_ops"],
+            "device_s": [[i, c.device_s] for i, c in sorted(r["calls"].items())]}
+    return hashlib.sha256(json.dumps(core).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("name", sorted(REDUCED))
+def test_chip_traces_reduce_as_with_a_list_of_kernels(name):
+    data = Path(__file__).parent / "data" / name
+    r = tr.reduce(tr.load(gzip.decompress(data.read_bytes())))
+    assert _reduced_digest(r) == REDUCED[name]
+    prefills = [c for c in r["calls"].values() if c.kind == "prefill"]
+    assert prefills
+    for c in r["calls"].values():
+        assert c.kernel_n == ({"flash_attention": 16} if c.kind == "prefill"
+                              else {})
+        assert set(c.kernel_s) == set(c.kernel_n)
 
 
 def _run_data(chip, model, calls_log):

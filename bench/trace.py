@@ -131,10 +131,11 @@ def _best_overlap(starts, spans, a, b):
     return best
 
 
-def reduce(planes: dict, kernels: tuple = ()) -> dict | None:
-    """Busy time, idle gaps, per-call device and kernel time and the top
-    device operations inside the ``bench.window`` span. ``None`` where the
-    trace has no such span or no device plane."""
+def reduce(planes: dict) -> dict | None:
+    """Busy time, idle gaps, per-call device time, the time and count of
+    every kernel in each call by its name, and the top device operations
+    inside the ``bench.window`` span. ``None`` where the trace has no such
+    span or no device plane."""
     host, calls, window = [], {}, None
     for pname, lines in planes.items():
         if not pname.startswith("/host"):
@@ -197,7 +198,7 @@ def reduce(planes: dict, kernels: tuple = ()) -> dict | None:
                 ops_by[f"{kind or 'other'}: {short_name(op.name)}"] += \
                     op.end - op.start
             kernel = _kernel(op.name)
-            if kernel in kernels and i is not None:
+            if kernel is not None and i is not None:
                 c = calls[i]
                 c.kernel_s[kernel] = c.kernel_s.get(kernel, 0.0) \
                     + op.end - op.start

@@ -2,15 +2,16 @@
 made on the device in one jitted call in the type they are served in.
 
 The benchmark makes the weights itself so that its reference takes nothing
-the program made. The layout (``embed``, ``final_norm``, one stacked block
-per position of the layer pattern under ``scan``, leftover layers under
-``rem``) is checked against the program's own ``init_params`` by shape
-(``check_layout``), never by value. Scales follow the usual fan-in rule.
+the program made. What the weights are is the configuration's model module
+(``bench/models/<config>.py``), found by name: its ``make(key, m, dtype)``
+returns the tree, drawing each leaf with ``normal``. This file holds only
+what every configuration shares: the seed's key, the draw, the one jitted
+call, and the check of the layout against the program's own
+``init_params`` by shape (``check_layout``), never by value.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +27,7 @@ def seed_key(seed: int, stream: str):
     return jax.random.wrap_key_data(jnp.asarray(state, jnp.uint32))
 
 
-def _normal(key, n: tuple, shape: tuple, dtype, scale: float):
+def normal(key, n: tuple, shape: tuple, dtype, scale: float):
     """Normal(0, scale) of shape ``n + shape``; a stacked leaf (``n`` set)
     is drawn one layer at a time so that no float32 draw of the whole
     stack is ever held."""
@@ -36,106 +37,23 @@ def _normal(key, n: tuple, shape: tuple, dtype, scale: float):
     return jax.lax.map(draw, jax.random.split(key, n[0]))
 
 
-def _norm(m: dict, n: tuple, dtype) -> dict:
-    if m["norm"] == "nonparam_ln":
-        return {}
-    if m["norm"] == "rmsnorm":
-        return {"scale": jnp.ones(n + (m["d_model"],), dtype)}
-    raise ValueError(m["norm"])
-
-
-def _attn(m: dict, n: tuple, key, dtype) -> dict:
-    D, H, K, hd = m["d_model"], m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    k = jax.random.split(key, 4)
-    s_in = 1.0 / math.sqrt(D)
-    s_out = 1.0 / math.sqrt(H * hd * 2 * m["num_layers"])
-    return {"wq": _normal(k[0], n, (D, H * hd), dtype, s_in),
-            "wk": _normal(k[1], n, (D, K * hd), dtype, s_in),
-            "wv": _normal(k[2], n, (D, K * hd), dtype, s_in),
-            "wo": _normal(k[3], n, (H * hd, D), dtype, s_out)}
-
-
-def _mlp(m: dict, n: tuple, key, dtype) -> dict:
-    D, F = m["d_model"], m["d_ff"]
-    k = jax.random.split(key, 3)
-    s_in = 1.0 / math.sqrt(D)
-    p = {"w1": _normal(k[0], n, (D, F), dtype, s_in),
-         "w2": _normal(k[1], n, (F, D), dtype,
-                       1.0 / math.sqrt(F * 2 * m["num_layers"]))}
-    if m["gated"]:
-        p["w3"] = _normal(k[2], n, (D, F), dtype, s_in)
-    return p
-
-
-def _ssd(m: dict, n: tuple, key, dtype) -> dict:
-    D, N, P = m["d_model"], m["ssm_state"], m["ssm_head_dim"]
-    di = m["ssm_expand"] * D
-    H = di // P
-    conv_ch = di + 2 * N
-    k = jax.random.split(key, 4)
-    f32 = jnp.float32
-    return {
-        "in_proj": _normal(k[0], n, (D, 2 * di + 2 * N + H), dtype,
-                           1.0 / math.sqrt(D)),
-        "conv_w": _normal(k[1], n, (m["ssm_conv"], conv_ch), dtype,
-                          1.0 / math.sqrt(m["ssm_conv"])),
-        "conv_b": jnp.zeros(n + (conv_ch,), dtype),
-        "A_log": jnp.broadcast_to(jnp.log(jnp.linspace(1.0, 16.0, H)), n + (H,)
-                                  ).astype(f32),
-        "D": jnp.ones(n + (H,), f32),
-        "dt_bias": jax.random.uniform(k[2], n + (H,), f32, math.log(1e-3),
-                                      math.log(1e-1)),
-        "norm_scale": jnp.ones(n + (di,), dtype),
-        "out_proj": _normal(k[3], n, (di, D), dtype,
-                            1.0 / math.sqrt(di * 2 * m["num_layers"])),
-    }
-
-
-MIXERS = {"attn": _attn, "ssd": _ssd}
-FFNS = {"mlp": _mlp}
-
-
-def _block(m: dict, kind, n: tuple, key, dtype) -> dict:
-    mixer, ffn = kind
-    k1, k2 = jax.random.split(key)
-    p = {"norm1": _norm(m, n, dtype), "mixer": MIXERS[mixer](m, n, k1, dtype)}
-    if ffn is not None:
-        p["norm2"] = _norm(m, n, dtype)
-        p["ffn"] = FFNS[ffn](m, n, k2, dtype)
-    return p
-
-
-def _make(key, m: dict, dtype):
-    pattern = [tuple(k) for k in m["block_pattern"]]
-    per, n_full = len(pattern), m["num_layers"] // len(pattern)
-    rem = m["num_layers"] % per
-    keys = jax.random.split(key, per + rem + 2)
-    D, V = m["d_model"], m["vocab_size"]
-    embed = {"embedding": _normal(keys[0], (), (V, D), dtype, 0.02)}
-    if not m["tie_embeddings"]:
-        embed["lm_head"] = _normal(keys[1], (), (D, V), dtype, 1.0 / math.sqrt(D))
-    return {"embed": embed, "final_norm": _norm(m, (), dtype),
-            "scan": tuple(_block(m, pattern[j], (n_full,), keys[2 + j], dtype)
-                          for j in range(per) if n_full),
-            "rem": tuple(_block(m, pattern[i], (), keys[2 + per + i], dtype)
-                         for i in range(rem))}
-
-
 def _frozen(m: dict):
     return tuple(sorted((k, tuple(map(tuple, v)) if k == "block_pattern" else v)
                         for k, v in m.items()))
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _make_jit(key, frozen, dtype):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _make_jit(key, mod, frozen, dtype):
     m = {k: [list(x) for x in v] if k == "block_pattern" else v
          for k, v in frozen}
-    return _make(key, m, dtype)
+    return mod.make(key, m, dtype)
 
 
-def make_weights(model: dict, seed: int, dtype: str):
-    """All weights of ``model`` from ``seed``, in ``dtype``, on the device."""
-    return _make_jit(seed_key(seed, "weights"), _frozen(model), DTYPES[dtype])
+def make_weights(mod, model: dict, seed: int, dtype: str):
+    """All weights of ``model`` from ``seed``, in ``dtype``, on the device,
+    as the model module ``mod`` lays them out."""
+    return _make_jit(seed_key(seed, "weights"), mod, _frozen(model),
+                     DTYPES[dtype])
 
 
 def check_layout(params, program_shapes) -> None:
