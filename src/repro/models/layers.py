@@ -184,81 +184,78 @@ def attention_full(params, x, cfg: ArchConfig, *, window: int = 0,
     return out.reshape(B, S, H * hd) @ params["wo"], (k, v)
 
 
-def attention_decode(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *,
-                     window: int = 0):
-    """One-token decode. x: (B, 1, D); cache_[kv]: (B, S_max, K, hd);
-    pos: scalar int32 — current write position, or (B,) int32 for per-row
-    positions (continuous batching: each slot decodes at its own depth).
-    Returns (out, new_k, new_v)."""
-    B, _, D = x.shape
-    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    S_max = cache_k.shape[1]
-    q = _split_heads(x @ params["wq"], H, hd)
-    k = _split_heads(x @ params["wk"], K, hd)
-    v = _split_heads(x @ params["wv"], K, hd)
-    per_row = jnp.ndim(pos) == 1
-    posb = pos[:, None] if per_row else jnp.full((B, 1), pos)
-    q = rope(q, posb, cfg.rope_theta)
-    k = rope(k, posb, cfg.rope_theta)
-    if per_row:
-        rows = jnp.arange(B)
-        cache_k = cache_k.at[rows, pos].set(k[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[rows, pos].set(v[:, 0].astype(cache_v.dtype))
-    else:
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
+def _attend_cached_and_new(q, k_new, v_new, cache_k, cache_v, mask, dtype):
+    """One query token per row against the cached keys where ``mask``
+    (B, T) holds and against its own new key, with one softmax over both.
+    The same arithmetic as attending over a cache that already holds the
+    new row, without writing it: q (B, 1, H, hd); k_new, v_new (B, K, hd);
+    cache_[kv] (B, T, K, hd). Returns (B, 1, H * hd) in ``dtype``."""
+    B, _, H, hd = q.shape
+    K = cache_k.shape[2]
     G = H // K
     qg = q.reshape(B, 1, K, G, hd)
     scores = jnp.einsum("bskgh,btkh->bkgst", qg, cache_k) / math.sqrt(hd)
-    trange = jnp.arange(S_max)
-    mask = trange[None, :] <= posb                        # (B, S_max)
+    scores = jnp.where(mask[:, None, None, None, :], scores, NEG_INF)
+    own = jnp.einsum("bskgh,bkh->bkgs", qg, k_new)[..., None] / math.sqrt(hd)
+    w = jax.nn.softmax(jnp.concatenate([scores, own], axis=-1)
+                       .astype(jnp.float32), axis=-1).astype(dtype)
+    out = (jnp.einsum("bkgst,btkh->bskgh", w[..., :-1], cache_v,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bkgs,bkh->bskgh", w[..., -1], v_new,
+                        preferred_element_type=jnp.float32))
+    return out.astype(dtype).reshape(B, 1, H * hd)
+
+
+def _decode_qkv(params, x, pos, cfg: ArchConfig, cache_dtype):
+    """The new token's query (B, 1, H, hd) and its K/V rows (B, K, hd) in
+    the cache's dtype, RoPE'd at ``pos`` (scalar, or (B,) per row); and
+    the positions as (B, 1)."""
+    B = x.shape[0]
+    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    q = _split_heads(x @ params["wq"], H, hd)
+    k = _split_heads(x @ params["wk"], K, hd)
+    v = _split_heads(x @ params["wv"], K, hd)
+    posb = pos[:, None] if jnp.ndim(pos) == 1 else jnp.full((B, 1), pos)
+    q = rope(q, posb, cfg.rope_theta)
+    k = rope(k, posb, cfg.rope_theta)
+    return q, k[:, 0].astype(cache_dtype), v[:, 0].astype(cache_dtype), posb
+
+
+def attention_decode(params, x, cache_k, cache_v, pos, cfg: ArchConfig, *,
+                     window: int = 0):
+    """One-token decode. x: (B, 1, D); cache_[kv]: (B, S_max, K, hd);
+    pos: scalar int32 — the new token's position, or (B,) int32 for per-row
+    positions (continuous batching: each slot decodes at its own depth).
+
+    The cache is only read: the new token attends to the positions before
+    ``pos`` (within ``window``) and to itself. Returns (out, new_k, new_v),
+    the new rows (B, K, hd) that the caller writes at ``pos``."""
+    q, k, v, posb = _decode_qkv(params, x, pos, cfg, cache_k.dtype)
+    trange = jnp.arange(cache_k.shape[1])
+    mask = trange[None, :] < posb                         # (B, S_max)
     if window > 0:
         mask &= trange[None, :] > posb - window
-    scores = jnp.where(mask[:, None, None, None, :], scores, NEG_INF)
-    w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
-    out = jnp.einsum("bkgst,btkh->bskgh", w, cache_v).reshape(B, 1, H * hd)
-    return out @ params["wo"], cache_k, cache_v
+    out = _attend_cached_and_new(q, k, v, cache_k, cache_v, mask, x.dtype)
+    return out @ params["wo"], k, v
 
 
 def attention_decode_ring(params, x, cache_k, cache_v, pos, cfg: ArchConfig):
     """One-token decode against a ring (window-sized) KV cache of length L.
 
-    Slot = position % L. Because the ring holds exactly the last L positions,
-    the only masking needed is "slot already written" (arange(L) <= pos, which
-    is all-true once pos >= L). Keys are RoPE'd at their absolute position at
-    write time, so relative phases are correct. ``pos`` may be scalar or (B,)
-    for per-row decode depths (continuous batching).
+    Slot = position % L. The ring holds the last L positions before
+    ``pos``; the new token attends to every slot already written except
+    slot ``pos % L``, whose position ``pos - L`` its own row replaces, and
+    to itself. Keys are RoPE'd at their absolute position at write time,
+    so relative phases are correct. ``pos`` may be scalar or (B,) for
+    per-row decode depths (continuous batching). Returns (out, new_k,
+    new_v), the new rows (B, K, hd) that the caller writes at ``pos % L``.
     """
-    B, _, D = x.shape
-    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    q, k, v, posb = _decode_qkv(params, x, pos, cfg, cache_k.dtype)
     L = cache_k.shape[1]
-    q = _split_heads(x @ params["wq"], H, hd)
-    k = _split_heads(x @ params["wk"], K, hd)
-    v = _split_heads(x @ params["wv"], K, hd)
-    per_row = jnp.ndim(pos) == 1
-    posb = pos[:, None] if per_row else jnp.full((B, 1), pos)
-    q = rope(q, posb, cfg.rope_theta)
-    k = rope(k, posb, cfg.rope_theta)
-    slot = jax.lax.rem(pos, L)
-    if per_row:
-        rows = jnp.arange(B)
-        cache_k = cache_k.at[rows, slot].set(k[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[rows, slot].set(v[:, 0].astype(cache_v.dtype))
-    else:
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k.astype(cache_k.dtype), (0, slot, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v.astype(cache_v.dtype), (0, slot, 0, 0))
-    G = H // K
-    qg = q.reshape(B, 1, K, G, hd)
-    scores = jnp.einsum("bskgh,btkh->bkgst", qg, cache_k) / math.sqrt(hd)
-    mask = jnp.arange(L)[None, :] <= posb                 # (B, L)
-    scores = jnp.where(mask[:, None, None, None, :], scores, NEG_INF)
-    w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
-    out = jnp.einsum("bkgst,btkh->bskgh", w, cache_v).reshape(B, 1, H * hd)
-    return out @ params["wo"], cache_k, cache_v
+    slots = jnp.arange(L)[None, :]
+    mask = (slots < posb) & (slots != jax.lax.rem(posb, L))   # (B, L)
+    out = _attend_cached_and_new(q, k, v, cache_k, cache_v, mask, x.dtype)
+    return out @ params["wo"], k, v
 
 
 # ---------------------------------------------------------------------------

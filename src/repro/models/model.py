@@ -3,7 +3,9 @@
 The layer stack is grouped into ``n_full`` repeats of the config's block
 pattern (period p) plus ``rem`` leftover layers. The repeats run under one
 ``lax.scan`` whose xs are the stacked per-repeat parameters (and, when
-decoding, the stacked per-repeat caches, which are threaded back out as ys).
+decoding, the stacked attention caches, which the body only reads: its ys
+are their new K/V rows, written into the caches after the scan; state
+mixers' stacked states are carried and replaced layer by layer).
 Compile cost is therefore O(period + rem) block bodies regardless of depth.
 
 Modes:
@@ -195,22 +197,29 @@ def _rglru_cache_from_prefill(mixer_params, h, cfg: ArchConfig):
     return {"h": hseq[:, -1], "conv": xw[:, S - (cfg.rnn_conv - 1):, :]}
 
 
+def _is_ring(cfg: ArchConfig, mixer: str, opts: ModelOptions, L: int) -> bool:
+    """An attention cache of length ``L`` is a ring: it holds exactly the
+    last ``L`` positions, position p in slot p % L."""
+    w = effective_window(cfg, mixer, opts)
+    return w > 0 and L <= w
+
+
 def apply_block_decode(params, x, cache, pos, cfg: ArchConfig, kind,
                        opts: ModelOptions):
-    """One-token block. Returns (x, new_cache)."""
+    """One-token block. Returns (x, new): for an attention block, which only
+    reads its cache, the new token's K/V rows ``{"k", "v"}`` of (B, K, hd)
+    for ``_write_decoded``; for a state mixer, its new cache, whole."""
     mixer, ffn = kind
     h = layers.apply_norm(params["norm1"], x, cfg)
     if mixer in ("attn", "attn_window"):
-        w = effective_window(cfg, mixer, opts)
-        L = cache["k"].shape[1]
-        if w > 0 and L <= w:
-            # ring cache: holds exactly the last L positions
+        if _is_ring(cfg, mixer, opts, cache["k"].shape[1]):
             out, ck, cv = layers.attention_decode_ring(
                 params["mixer"], h, cache["k"], cache["v"], pos, cfg)
         else:
             # full-length cache (window masking if any)
             out, ck, cv = layers.attention_decode(
-                params["mixer"], h, cache["k"], cache["v"], pos, cfg, window=w)
+                params["mixer"], h, cache["k"], cache["v"], pos, cfg,
+                window=effective_window(cfg, mixer, opts))
         new_cache = {"k": ck, "v": cv}
     elif mixer == "ssd":
         out, new_cache = ssm.ssd_step(params["mixer"], h, cache, cfg)
@@ -235,6 +244,27 @@ def apply_block_decode(params, x, cache, pos, cfg: ArchConfig, kind,
             out2 = layers.apply_mlp(params["ffn"], h2, cfg)
         x = x + out2
     return x, new_cache
+
+
+def _write_decoded(cache, new, pos, cfg: ArchConfig, kind,
+                   opts: ModelOptions):
+    """A block's cache after one decode step, from what
+    ``apply_block_decode`` returned: an attention block's new rows written
+    at ``pos`` (``pos % L`` in a ring), scalar or per row (B,); a state
+    mixer's new cache as it is. Leaves may carry leading (repeat) axes.
+    With the cache donated to the program, the write is in place."""
+    mixer, _ = kind
+    if mixer not in ("attn", "attn_window"):
+        return new
+    L = cache["k"].shape[-3]
+    at = jax.lax.rem(pos, L) if _is_ring(cfg, mixer, opts, L) else pos
+
+    def put(big, rows):              # (..., B, L, K, hd), (..., B, K, hd)
+        if jnp.ndim(at) == 1:
+            return big.at[..., jnp.arange(big.shape[-4]), at, :, :].set(rows)
+        start = (0,) * (big.ndim - 3) + (at, 0, 0)
+        return jax.lax.dynamic_update_slice(big, rows[..., None, :, :], start)
+    return {n: put(cache[n], new[n]) for n in ("k", "v")}
 
 
 # ---------------------------------------------------------------------------
@@ -408,8 +438,13 @@ def prefill(params, batch: dict, cfg: ArchConfig, opts: ModelOptions,
 
 def decode_step(params, token, pos, cache, cfg: ArchConfig,
                 opts: ModelOptions):
-    """One decode step. token: (B,) int32; pos: scalar int32.
-    Returns (logits (B,V), new cache)."""
+    """One decode step. token: (B,) int32; pos: scalar int32, or (B,)
+    per-row positions. Returns (logits (B,V), new cache).
+
+    Nothing is copied out of or into ``cache`` as a whole, so a program
+    that is donated it updates it in place (see the module docstring). A
+    state mixer's state is carried through the scan rather than read as xs
+    and written as ys: XLA copies a donated buffer that is both."""
     n_full, rem = _pattern_layout(cfg)
     p = len(cfg.block_pattern)
     kinds = cfg.layer_kinds
@@ -417,24 +452,42 @@ def decode_step(params, token, pos, cache, cfg: ArchConfig,
 
     new_cache = {"scan": (), "rem": ()}
     if n_full > 0:
-        def body(h, xs):
-            params_j, cache_j = xs
-            new_cs = []
-            for j in range(p):
-                h, c = apply_block_decode(params_j[j], h, cache_j[j], pos,
-                                          cfg, kinds[j], opts)
-                new_cs.append(c)
-            return h, tuple(new_cs)
+        attn = [kinds[j][0] in ("attn", "attn_window") for j in range(p)]
+        read = tuple(c if a else {} for c, a in zip(cache["scan"], attn))
+        states = tuple({} if a else c for c, a in zip(cache["scan"], attn))
 
-        x, scan_caches = jax.lax.scan(body, x, (params["scan"], cache["scan"]))
-        new_cache["scan"] = scan_caches
+        def body(carry, xs):
+            h, states = carry
+            i, params_j, read_j = xs
+            states, rows = list(states), []
+            for j in range(p):
+                c = read_j[j] if attn[j] else jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False),
+                    states[j])
+                h, new = apply_block_decode(params_j[j], h, c, pos, cfg,
+                                            kinds[j], opts)
+                if attn[j]:
+                    rows.append(new)
+                else:
+                    states[j] = jax.tree.map(
+                        lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                            a, n.astype(a.dtype), i, 0), states[j], new)
+                    rows.append({})
+            return (h, tuple(states)), tuple(rows)
+
+        (x, states), rows = jax.lax.scan(
+            body, (x, states), (jnp.arange(n_full), params["scan"], read))
+        new_cache["scan"] = tuple(
+            _write_decoded(cache["scan"][j], rows[j] if attn[j] else states[j],
+                           pos, cfg, kinds[j], opts) for j in range(p))
 
     rem_caches = []
     for i in range(rem):
         kind = kinds[n_full * p + i]
         x, c = apply_block_decode(params["rem"][i], x, cache["rem"][i], pos,
                                   cfg, kind, opts)
-        rem_caches.append(c)
+        rem_caches.append(_write_decoded(cache["rem"][i], c, pos, cfg, kind,
+                                         opts))
     new_cache["rem"] = tuple(rem_caches)
 
     x = layers.apply_norm(params["final_norm"], x, cfg)

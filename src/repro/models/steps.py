@@ -102,7 +102,9 @@ def prefill_step(params: Pytree, batch: dict, cfg: ArchConfig,
 def decode_step(params: Pytree, cache: Pytree, batch: dict, cfg: ArchConfig,
                 opts: M.ModelOptions):
     """``batch["pos"]`` may be a scalar (lock-step batch) or a (B,) vector of
-    per-slot positions (continuous batching)."""
+    per-slot positions (continuous batching). A program donated ``cache``
+    writes only each slot's new K/V rows (and the state mixers' states)
+    into it, in place."""
     logits, new_cache = M.decode_step(params, batch["token"], batch["pos"],
                                       cache, cfg, opts)
     return logits, new_cache
@@ -114,8 +116,9 @@ def prefill_into_slot_step(params: Pytree, cache: Pytree, batch: dict, slot,
     """Prefill ONE request (leading batch dim of 1) and insert its KV/state
     into row ``slot`` of an existing batched cache — the admission primitive
     of continuous batching: a new request joins a running pool without
-    re-prefilling the other slots. Returns (last-position logits (V,),
-    updated batched cache)."""
+    re-prefilling the other slots. A program donated ``cache`` writes only
+    row ``slot``, in place. Returns (last-position logits (V,), updated
+    batched cache)."""
     logits, one = M.prefill(params, batch, cfg, opts, cache_len)
     return logits[0], M.insert_cache_slot(cache, one, slot)
 

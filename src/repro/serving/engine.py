@@ -33,8 +33,10 @@ runs, so they share the device trace's clock) mark each part of a step::
       serving.retire (request_id)
     host.gc (generation)               a Python garbage collection
 
-and always-on counters, under ``report()["host"]`` and
-``report()["pipeline"]``, sum the same phases and count the read-backs.
+and always-on counters, under ``report()["host"]``,
+``report()["pipeline"]`` and ``report()["cache"]``, sum the same phases,
+count the read-backs, and count the program calls that updated the slot
+cache in place.
 """
 from __future__ import annotations
 
@@ -57,9 +59,10 @@ from repro.models.steps import (make_jitted_decode, make_jitted_prefill,
                                 make_jitted_prefill_into_slot)
 
 
-# Programs the continuous engine keeps dispatched and unread. The cache is
-# not donated, so each program in flight holds a whole cache of its own
-# until it has run; two queued behind a running one keep the device busy.
+# Programs the continuous engine keeps dispatched and unread: how far the
+# host may run ahead of the device. Each program is donated the cache and
+# updates it in place, so a program in flight holds no cache of its own;
+# two queued behind a running one keep the device busy.
 MAX_IN_FLIGHT = 3
 
 
@@ -323,8 +326,12 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
     slot active and nothing queued reads back everything still in flight,
     so every request submitted has its ``output`` once the engine is idle.
     Before it dispatches a program, the host reads back the oldest while
-    ``MAX_IN_FLIGHT`` are unread, which bounds the caches a burst of
-    admissions holds on the device.
+    ``MAX_IN_FLIGHT`` are unread, which bounds how far the host runs ahead
+    of the device in a burst of admissions.
+
+    Both programs are donated the slot cache: a prefill writes only its
+    slot and a decode only each slot's new K/V row (or each state mixer's
+    state), in the same buffers, with no copy of the rest of the cache.
 
     Greedy decoding is identical to the static engine's: the prefill's
     last-position argmax is the first generated token, and each decode step
@@ -343,11 +350,13 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
         self.cache_len = cache_len
         self.opts = opts or serving_options()
         self.queue: list[Request] = []
+        # the cache is the programs' argument 1, handed over on every call
         self._prefill_slot = make_jitted_prefill_into_slot(
-            cfg, self.opts, cache_len)
-        self._decode = make_jitted_decode(cfg, self.opts)
+            cfg, self.opts, cache_len, donate_argnums=1)
+        self._decode = make_jitted_decode(cfg, self.opts, donate_argnums=1)
         dtype = jax.tree.leaves(params)[0].dtype
         self.cache = M.init_cache(cfg, max_slots, cache_len, dtype, self.opts)
+        self._cache_bytes = sum(a.nbytes for a in jax.tree.leaves(self.cache))
         self._slot_req: list[Optional[Request]] = [None] * max_slots
         self._slot_pos = np.zeros(max_slots, np.int32)   # next write position
         # the occupant's tokens on the host, and its tokens not yet dispatched
@@ -406,8 +415,10 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
             with self._phase("launch_s", "serving.prefill.launch"):
                 tokens = jnp.asarray(req.tokens[None, :], jnp.int32)
                 at = jnp.asarray(slot, jnp.int32)
+                handed = self.cache
                 logits, self.cache = self._prefill_slot(
-                    self.params, self.cache, {"tokens": tokens}, at)
+                    self.params, handed, {"tokens": tokens}, at)
+                self._count_cache(handed)
                 self._next, first = _seat_first_token(self._next, logits, at)
                 out: list[int] = []
                 self._enqueue_read(first, [(0, req, out)])
@@ -416,6 +427,13 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
             self._slot_left[slot] = req.max_new_tokens - 1
             self._slot_pos[slot] = len(req.tokens)
             self.stats["prefills"] += 1
+
+    def _count_cache(self, handed) -> None:
+        """Count a program call, and whether it consumed the cache it was
+        handed: every leaf deleted, so the program updates it in place."""
+        self._cache["calls"] += 1
+        self._cache["in_place"] += all(a.is_deleted()
+                                       for a in jax.tree.leaves(handed))
 
     def _read_back(self, keep: int, overlapped: bool) -> list[Request]:
         """Bring the tokens of all but the newest ``keep`` programs in
@@ -492,9 +510,10 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
                     # host buffer, and the positions advance before the
                     # step runs
                     pos = jnp.asarray(self._slot_pos.copy())
+                    handed = self.cache
                     logits, self.cache = self._decode(
-                        self.params, self.cache,
-                        {"token": self._next, "pos": pos})
+                        self.params, handed, {"token": self._next, "pos": pos})
+                    self._count_cache(handed)
                     self._next = _greedy_tokens(logits)
                     self._enqueue_read(self._next, [
                         (s, self._slot_req[s], self._slot_out[s])
@@ -538,6 +557,7 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
                       "gc_pauses": 0, "gc_pause_s": 0.0,
                       "gc_pause_max_s": 0.0}
         self._pipeline = {"readbacks": 0, "overlapped": 0, "flushes": 0}
+        self._cache = {"calls": 0, "in_place": 0}
 
     def _count_gc(self, pause: float) -> None:
         h = self._host
@@ -570,6 +590,11 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
         ``readbacks``, of which ``overlapped`` (a later program was already
         dispatched, so the device had work while the host waited) and
         ``flushes`` (the engine went idle and read everything in flight).
+
+        ``cache`` holds ``bytes``, the slot pool's cache, and counts the
+        program calls since the last ``reset_stats()``: ``calls`` (prefills
+        and decodes) and ``in_place``, those that consumed the cache handed
+        to them (donated, so written in place rather than copied).
         """
         lat = sorted(self._latencies)
         n = len(lat)
@@ -591,6 +616,7 @@ class ContinuousBatchingEngine(_EngineStatsMixin):
                      "host_s": self.stats["wall_s"] - self._host["launch_s"]
                      - self._host["wait_s"]},
             "pipeline": dict(self._pipeline),
+            "cache": {"bytes": self._cache_bytes, **self._cache},
         }
 
 

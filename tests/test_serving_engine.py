@@ -1,10 +1,12 @@
 """Continuous-batching engine tests: greedy-token equivalence with the
 static engine, slot reuse within one drain, deadline (EDF) admission, the
-prefill-into-slot model step, stats sanity, and the engine's own
-instrumentation (host counters, request stamps, profiler spans, program
-names)."""
+prefill-into-slot model step, stats sanity, the slot cache donated to both
+programs, and the engine's own instrumentation (host counters, request
+stamps, profiler spans, program names)."""
 import gc
+import json
 import math
+import re
 import sys
 import time
 from pathlib import Path
@@ -22,8 +24,10 @@ from repro.serving import (ContinuousBatchingEngine, Request, ServingEngine,
                            StreamSimulator)
 from repro.serving.engine import MAX_IN_FLIGHT
 
-# the checkout's root, for the benchmark's trace reader
-sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+# the checkout's root, for the benchmark's trace reader and configurations
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+from bench import harness  # noqa: E402
 from bench import trace as tr  # noqa: E402
 
 CACHE_LEN = 48
@@ -481,6 +485,75 @@ def test_pipeline_counters():
     eng.reset_stats()
     assert eng.report()["pipeline"] == {"readbacks": 0, "overlapped": 0,
                                         "flushes": 0}
+
+
+# -- the slot cache, donated to both programs ----------------------------------
+
+def _aliased_cache_params(compiled) -> tuple[set, set]:
+    """(numbers of the entry parameters that hold the cache, numbers of
+    the parameters aliased to an output) of a compiled program."""
+    text = compiled.as_text()
+    header = text.splitlines()[0]
+    aliased = {int(n) for n in re.findall(r"\{[\d,]*\}: \((\d+),", header)}
+    entry = text[text.index("\nENTRY "):]
+    cache = {int(n) for n in re.findall(
+        r"%cache_\S* = \S+ parameter\((\d+)\)", entry)}
+    return cache, aliased
+
+
+@pytest.mark.parametrize("config", ["olmo-1b", "mamba2-2.7b"])
+def test_engine_programs_alias_every_cache_leaf(config):
+    """At the benchmark's rehearsal sizes, the continuous engine's prefill
+    and decode programs are compiled with every cache leaf aliased to an
+    output: the cache is donated, and updated in place."""
+    conf = json.loads((ROOT / "bench" / "configs" / f"{config}.json")
+                      .read_text())
+    cfg = harness.program_config(conf["arch"], conf["rehearsal"]["model"],
+                                 rehearse=True)
+    params = M.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=4, cache_len=conf["rehearsal"]["cache_len"])
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    programs = {
+        "prefill": eng._prefill_slot.lower(params, eng.cache,
+                                           {"tokens": i32(1, 272)}, i32()),
+        "decode": eng._decode.lower(params, eng.cache,
+                                    {"token": i32(4), "pos": i32(4)}),
+    }
+    leaves = len(jax.tree.leaves(eng.cache))
+    for name, lowered in programs.items():
+        cache, aliased = _aliased_cache_params(lowered.compile())
+        assert len(cache) == leaves, name
+        assert cache <= aliased, (name, cache, aliased)
+
+
+def test_programs_consume_the_cache_and_count_it():
+    """After ``step()`` the arrays of the cache handed to its programs are
+    deleted and the engine holds the programs' outputs; ``report()["cache"]``
+    counts every call as in place, keeps the pool's bytes, and its counts
+    zero on ``reset_stats()``. An undonated program counts as a copy."""
+    cfg, params = _setup()
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
+                                   cache_len=CACHE_LEN)
+    pool = sum(a.nbytes for a in jax.tree.leaves(eng.cache))
+    handed = eng.cache
+    steps = _serve_steps(eng, cfg, 4)
+    next(steps)
+    assert all(a.is_deleted() for a in jax.tree.leaves(handed))
+    assert not any(a.is_deleted() for a in jax.tree.leaves(eng.cache))
+    for _ in steps:
+        pass
+    c = eng.report()["cache"]
+    assert c["calls"] == eng.stats["prefills"] + eng.stats["decode_steps"]
+    assert c == {"bytes": pool, "calls": c["calls"], "in_place": c["calls"]}
+    eng.reset_stats()
+    assert eng.report()["cache"] == {"bytes": pool, "calls": 0, "in_place": 0}
+
+    eng._decode = make_jitted_decode(cfg, eng.opts)
+    for _ in _serve_steps(eng, cfg, 2):
+        pass
+    c = eng.report()["cache"]
+    assert c["in_place"] == eng.stats["prefills"] < c["calls"]
 
 
 def test_next_decode_dispatched_before_previous_is_read():
